@@ -220,23 +220,58 @@ def conv2d_direct(x, w, *, stride: int = 1, padding: str = "SAME"):
         dimension_numbers=("NHWC", "HWIO", "NHWC")).astype(x.dtype)
 
 
+LANES, SUBLANES = 128, 8         # the TPU vreg tile a VMEM buffer pads to
+
+
+def _round_up(x: int, k: int) -> int:
+    return -(-x // k) * k
+
+
+def vmem_bytes(shape, dtype_bytes: int = 4) -> int:
+    """VMEM bytes one buffer of ``shape`` occupies: its last two dims pad
+    to the (8, 128) tile, so a (227, 227, 3) plane costs 128/3 times its
+    element count."""
+    shape = tuple(shape) or (1,)
+    *lead, sub, lane = (1,) * (2 - len(shape)) + shape
+    return (math.prod(lead) * _round_up(sub, SUBLANES)
+            * _round_up(lane, LANES) * dtype_bytes)
+
+
 def auto_c_block(hp: int, wp: int, c: int, *, batch: int = 1,
+                 groups: int = 1, max_block: int | None = None,
                  dtype_bytes: int = 4,
                  budget_bytes: int = 8 * 2 ** 20) -> int:
     """Channel block auto-sizing shared by the kernels and the HBM model.
 
-    Largest channel block (<= ``c``) whose *whole resident input block*
-    (batch, hp, wp, Cb) — the filter-cache grid keeps ``batch_block``
-    images' slabs in VMEM at once — fits the slab budget.  Every AlexNet
-    layer gets all of C resident even at batch_block=8 — the slab then
-    streams HBM->VMEM exactly once per image, with no re-fetch over the
-    channel-block reduction (paper §3.5: stream buffers hold whole
-    feature-map planes).  VGG-class 224x224 planes fall back to a smaller
-    block (the re-fetch trade documented in ``conv2d_hbm_bytes``).
+    Largest *lane-legal* channel block whose whole resident input block
+    (batch, hp, wp, Cb) fits the slab budget, counted as VMEM holds it:
+    padded to the (8, 128) tile and double-buffered by the grid pipeline
+    (the filter-cache grid keeps ``batch_block`` images' slabs resident).
+    A TPU block's last dim must be the whole array dim or a multiple of
+    128 lanes, so the answer is ``c`` itself (one ungrouped block) or a
+    multiple of 128 — grouped layers always take the latter, their
+    per-group channels zero-padded up to it (``grouped_channel_pad``).
+    ``max_block`` caps the block (the Winograd kernel's strided tile loads
+    need a slab of at most 128 lanes).
+
+    Every AlexNet plane stays whole at batch_block=8, so its slab streams
+    HBM->VMEM once per image (paper §3.5: stream buffers hold whole
+    feature-map planes).  A plane of at most 128 channels cannot split
+    below one lane tile: VGG's 224x224x64 stage keeps all 64 channels and
+    would need row-blocked input residency instead.
     """
-    per_chan = max(batch * hp * wp * dtype_bytes, 1)
-    fit = max(int(budget_bytes // per_chan), 1)
-    return c if fit >= c else max(min(fit, 128), 1)
+    def fits(cb):
+        return 2 * vmem_bytes((batch, hp, wp, cb), dtype_bytes) <= budget_bytes
+
+    cap = c if max_block is None else min(c, max_block)
+    if groups == 1 and cap == c and (c <= LANES or fits(c)):
+        return c
+    cb = _round_up(cap, LANES)
+    if max_block is not None:
+        cb = min(cb, max(max_block // LANES * LANES, LANES))
+    while cb > LANES and not fits(cb):
+        cb -= LANES
+    return cb
 
 
 def auto_pool_rows(ph_out: int, pwin: int, ps: int, *, align: int = 1,
@@ -246,16 +281,17 @@ def auto_pool_rows(ph_out: int, pwin: int, ps: int, *, align: int = 1,
     """Pooled-row block auto-sizing shared by the kernels and the HBM model.
 
     Largest ``align``-multiple pooled-row block whose full-channel epilogue
-    scratch (batch, conv rows, cols, kfull) fits the budget — ideally the
-    whole pooled extent, so the row loop collapses to one step and a
-    grouped layer's slab is never re-fetched (the grouped block index
-    cycles per row block; see ``conv2d_hbm_bytes``).  ``row_align`` rounds
-    the conv rows up to the Winograd tile size where applicable.
+    scratch (batch, conv rows, cols, kfull), padded to the (8, 128) VMEM
+    tile, fits the budget — ideally the whole pooled extent, so the row
+    loop collapses to one step and a grouped layer's slab is never
+    re-fetched (the grouped block index cycles per row block; see
+    ``conv2d_hbm_bytes``).  ``row_align`` rounds the conv rows up to the
+    Winograd tile size where applicable.
     """
     Pb = align * (-(-max(ph_out, 1) // align))
     while Pb > align:
         rows = -(-(ps * (Pb - 1) + pwin) // row_align) * row_align
-        if batch * rows * cols * kfull * dtype_bytes <= budget_bytes:
+        if vmem_bytes((batch, rows, cols, kfull), dtype_bytes) <= budget_bytes:
             break
         Pb -= align
     return Pb

@@ -1,26 +1,18 @@
-"""Version compatibility shims for Pallas TPU APIs.
+"""Pallas TPU compiler parameters shared by every kernel.
 
-The Pallas TPU compiler-params API was renamed across JAX releases
-(``TPUCompilerParams`` with string dimension semantics -> ``CompilerParams``
-with a ``GridDimensionSemantics`` enum).  Kernels call
-:func:`tpu_compiler_params` with ``"parallel"`` / ``"arbitrary"`` strings and
-this module translates to whatever the installed JAX expects.
+Kernels name each grid dimension :data:`PARALLEL` or :data:`ARBITRARY` and
+pass the scoped-VMEM request their launch plan derived.
 """
 from __future__ import annotations
 
 from jax.experimental.pallas import tpu as pltpu
 
-PARALLEL = "parallel"
-ARBITRARY = "arbitrary"
+PARALLEL = pltpu.GridDimensionSemantics.PARALLEL
+ARBITRARY = pltpu.GridDimensionSemantics.ARBITRARY
 
 
-def tpu_compiler_params(*dimension_semantics: str):
-    """Build compiler params with per-grid-dim semantics for any JAX version."""
-    if hasattr(pltpu, "TPUCompilerParams"):
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=tuple(dimension_semantics))
-    sem = []
-    for s in dimension_semantics:
-        enum = getattr(pltpu, "GridDimensionSemantics", None)
-        sem.append(getattr(enum, s.upper()) if enum is not None else s)
-    return pltpu.CompilerParams(dimension_semantics=tuple(sem))
+def tpu_compiler_params(*dimension_semantics, vmem_limit_bytes=None):
+    """Compiler params with per-grid-dim semantics and an optional
+    scoped-VMEM limit (``None`` keeps the chip's default)."""
+    return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics),
+                                vmem_limit_bytes=vmem_limit_bytes)

@@ -11,11 +11,16 @@ bias + ReLU + cross-channel-LRN + max-pool epilogue (``epilogue.py``,
 shared with the Winograd kernel) and the identical
 (B/Bb, row blocks, g*K blocks, C blocks, Bb) filter-cache grid.
 
-Compute shape: the conv is phrased as r GEMMs per grid step — for each
-filter row ``di`` the r width-taps are stacked into the contraction dim, so
-the MXU sees (rows*cols, r*Cb) @ (r*Cb, Kb) — rather than r^2 scalar-tap
-multiplies (PipeCNN's flattened-window trick, MXU-shaped like the Winograd
-formulation's n^2 GEMMs).
+Strided layers run as stride-1 convs by space-to-depth: the wrapper folds
+each s x s pixel block into s*s*C channels and the filters into
+ceil(r/s) x ceil(r/s) taps over those channels (zero taps pad r up to a
+multiple of s).  conv1's 11x11 stride 4 over 3 channels becomes a 3x3
+stride-1 conv over 48 channels — no strided value slices (Mosaic takes
+only stride-1 ones) and no 3-lane feature map padded to 128 lanes in VMEM.
+
+Compute shape: the conv is phrased as r^2 MXU GEMMs per grid step, one per
+filter tap: (rows*cols, Cb) @ (Cb, Kb) over the tap's shifted window of
+the VMEM-resident slab.
 
 Weight path (§3.5 filter prefetch, shared machinery in ``dma.py``): the
 filters arrive *tile-packed* in an ANY/HBM-space ref and move by explicit
@@ -30,8 +35,8 @@ Dataflow per grid step (image slot ``bi`` of the ``batch_block`` in
 flight):
 
 * the halo-padded input plane (Bb, Hp, Wp, Cb) is VMEM-resident; the step
-  slices its ``in_rows = s*(Rc-1)+r`` raw rows with stride-s strided
-  slices (no im2col tensor in HBM),
+  loads its ``in_rows = Rc - 1 + r`` rows tap by tap (no im2col tensor in
+  HBM),
 * channel blocks accumulate into a per-image VMEM scratch
   (``acc_ref[bi]``, the PE daisy-chain),
 * the last c block deposits bias+ReLU'd channels into the full-channel
@@ -41,7 +46,7 @@ flight):
 
 With ``pool`` set, each row step owns ``Pb`` pooled rows: it computes the
 ``Rc = ps*(Pb-1)+pwin`` conv rows those need but advances only
-``s*ps*Pb`` input rows, keeping the pool's output-side halo in VMEM (the
+``ps*Pb`` input rows, keeping the pool's output-side halo in VMEM (the
 direct analogue of the Winograd kernel's tile-aligned pooled-row blocks —
 no tile-alignment constraint here, since rows are computed directly).
 """
@@ -55,11 +60,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.winograd import auto_pool_rows
+from ...core.winograd import SUBLANES, auto_pool_rows
 from ..compat import tpu_compiler_params
 from . import dma
-from .epilogue import batch_blocks, channel_blocks, fused_epilogue, \
-    grouped_channel_pad, k_blocks
+from .epilogue import F32_DOT, batch_blocks, channel_blocks, fused_epilogue, \
+    grouped_channel_pad, k_blocks, vmem_limit
 
 
 def same_pad(extent: int, r: int, stride: int) -> tuple[int, int, int]:
@@ -75,16 +80,20 @@ class DirectPlan:
 
     Pure function of shapes + static params (``plan``), so the weight
     packing (``pack_weights``) can run ahead of the input tensor — the
-    cross-layer staging hook.
+    cross-layer staging hook.  Extents past the padding are those of the
+    stride-1 space-to-depth conv the kernel runs (``s`` x ``s`` pixel
+    blocks; ``s == 1`` leaves the layer as it is).
     """
-    r: int
-    s: int
+    r: int                  # filter taps after space-to-depth
+    s: int                  # layer stride == space-to-depth block
+    r_in: int               # the layer's own filter size
     g: int
-    C: int                  # channels per group
+    C: int                  # channels per group (s*s x the layer's)
     K: int                  # out channels per group
     out_h: int
     out_w: int
-    ph_lo: int
+    wc: int                 # computed cols: out_w up to the 8-row tile
+    ph_lo: int              # padding of the layer's input, in pixels
     pw_lo: int
     ph_out: int             # pooled output rows (== out_h when no pool)
     pw_out: int
@@ -116,6 +125,20 @@ class DirectPlan:
                               spatial=(self.r, self.r),
                               checksum=self.checksum)
 
+    @property
+    def vmem_limit_bytes(self) -> int:
+        tile = (1, *self.weights.tile_shape)
+        pipelined = [(self.Bb, self.Hp, self.Wp, self.Cb),
+                     (self.g * self.nkb, self.Kb),
+                     (self.Bb, self.rows_out, self.w_out, self.Kfull)]
+        scratch = [(self.Bb, self.Rc, self.wc, self.Kb),
+                   (self.Bb, self.g * self.nkb, self.Rc, self.wc, self.Kb)]
+        if self.weights.n_tiles == 1:
+            pipelined.append(tile)
+        else:
+            scratch.append((2, *tile[1:]))
+        return vmem_limit(pipelined, scratch)
+
 
 def plan(x_shape, w_shape, *, stride: int = 1, padding: str = "SAME",
          pool=None, groups: int = 1, row_block: int = 8,
@@ -123,20 +146,21 @@ def plan(x_shape, w_shape, *, stride: int = 1, padding: str = "SAME",
          k_block: int = 128, batch_block: int = 8,
          checksum: bool = False) -> DirectPlan:
     """Derive the full launch plan from shapes + static params."""
-    r, s, g = w_shape[0], stride, groups
+    r_in, s, g = w_shape[0], stride, groups
     assert w_shape[0] == w_shape[1], "square filters only"
     B, H, W, Ct = x_shape
     Kt = w_shape[-1]
     assert Ct % g == 0 and Kt % g == 0 and w_shape[2] == Ct // g, (
         "grouped conv shape mismatch")
-    C, K = Ct // g, Kt // g
+    K = Kt // g
     if padding == "SAME":
-        out_h, ph_lo, _ = same_pad(H, r, s)
-        out_w, pw_lo, _ = same_pad(W, r, s)
+        out_h, ph_lo, _ = same_pad(H, r_in, s)
+        out_w, pw_lo, _ = same_pad(W, r_in, s)
     else:
         ph_lo = pw_lo = 0
-        out_h, out_w = (H - r) // s + 1, (W - r) // s + 1
-    assert out_h >= 1 and out_w >= 1, (H, W, r, s, padding)
+        out_h, out_w = (H - r_in) // s + 1, (W - r_in) // s + 1
+    assert out_h >= 1 and out_w >= 1, (H, W, r_in, s, padding)
+    r, C = -(-r_in // s), s * s * (Ct // g)     # space-to-depth geometry
 
     Bb, Bp = batch_blocks(B, batch_block)
     if pool is not None:
@@ -153,48 +177,79 @@ def plan(x_shape, w_shape, *, stride: int = 1, padding: str = "SAME",
         else:
             Pb = min(pool_row_block, ph_out)
         Rc = ps * (Pb - 1) + pwin               # conv rows each step owns
-        step_in = s * ps * Pb                   # input rows advanced per step
+        step_in = ps * Pb                       # input rows advanced per step
         npr = -(-ph_out // Pb)
         rows_out, w_out = Pb, pw_out
     else:
         ph_out, pw_out = out_h, out_w
         Rc = min(row_block, out_h)
-        step_in = s * Rc
+        step_in = Rc
         npr = -(-out_h // Rc)
         rows_out, w_out = Rc, out_w
-    in_rows = s * (Rc - 1) + r                  # raw rows per step (w/ halo)
+    in_rows = Rc - 1 + r                        # raw rows per step (w/ halo)
     Hp = (npr - 1) * step_in + in_rows
-    Wp = s * (out_w - 1) + r
+    # the step computes whole 8-row tiles of columns, so a tap's (Rc, wc,
+    # Cb) window flattens into its GEMM operand without a relayout; the
+    # extra columns read zero padding and are never stored
+    wc = -(-out_w // SUBLANES) * SUBLANES
+    Wp = wc - 1 + r
 
-    Cb = channel_blocks(C, c_block, Hp, Wp, Bb)
+    Cb = channel_blocks(C, c_block, Hp, Wp, Bb, groups=g)
     Cp = C + (-C) % Cb
     Kb = k_blocks(K, k_block)
-    return DirectPlan(r=r, s=s, g=g, C=C, K=K, out_h=out_h, out_w=out_w,
-                      ph_lo=ph_lo, pw_lo=pw_lo, ph_out=ph_out, pw_out=pw_out,
-                      Rc=Rc, step_in=step_in, in_rows=in_rows, npr=npr,
-                      rows_out=rows_out, w_out=w_out, Hp=Hp, Wp=Wp,
-                      Bb=Bb, Bp=Bp, Cb=Cb, Cp=Cp, ncb=Cp // Cb,
-                      Kb=Kb, nkb=K // Kb, checksum=checksum)
+    return DirectPlan(r=r, s=s, r_in=r_in, g=g, C=C, K=K, out_h=out_h,
+                      out_w=out_w, wc=wc, ph_lo=ph_lo, pw_lo=pw_lo,
+                      ph_out=ph_out, pw_out=pw_out, Rc=Rc, step_in=step_in,
+                      in_rows=in_rows, npr=npr, rows_out=rows_out,
+                      w_out=w_out, Hp=Hp, Wp=Wp, Bb=Bb, Bp=Bp, Cb=Cb,
+                      Cp=Cp, ncb=Cp // Cb, Kb=Kb, nkb=K // Kb,
+                      checksum=checksum)
+
+
+def _filters_to_depth(w, p: DirectPlan):
+    """(r_in, r_in, C0, g*K) -> (r, r, s*s*C0, g*K): zero taps pad the
+    filter to r*s, then each s x s tap block folds into the channel dim in
+    the (row phase, col phase, channel) order of ``_input_to_depth``."""
+    s, r = p.s, p.r
+    if s == 1:
+        return w
+    pad = r * s - p.r_in
+    w = jnp.pad(w, ((0, pad), (0, pad), (0, 0), (0, 0)))
+    c0, kt = w.shape[2], w.shape[3]
+    w = w.reshape(r, s, r, s, c0, kt).transpose(0, 2, 1, 3, 4, 5)
+    return w.reshape(r, r, s * s * c0, kt)
+
+
+def _input_to_depth(x, p: DirectPlan):
+    """(B, Hp*s, Wp*s, g*C0) padded pixels -> (B, Hp, Wp, g*s*s*C0),
+    group-major so each group's folded channels stay contiguous."""
+    s = p.s
+    if s == 1:
+        return x
+    B, H, W, Ct = x.shape
+    c0 = Ct // p.g
+    x = x.reshape(B, H // s, s, W // s, s, p.g, c0)
+    x = x.transpose(0, 1, 3, 5, 2, 4, 6)
+    return x.reshape(B, H // s, W // s, p.g * s * s * c0)
 
 
 def pack_weights(w, p: DirectPlan):
-    """(r, r, C, g*K) -> (n_tiles, r, r, Cb, Kb) DMA tile layout."""
+    """(r_in, r_in, C0, g*K) -> (n_tiles, r, r, Cb, Kb) DMA tile layout."""
     r, g, C, K = p.r, p.g, p.C, p.K
+    w = _filters_to_depth(w, p)
     wg = jnp.moveaxis(w.reshape(r, r, C, g, K), 3, 0)       # (g, r, r, C, K)
     if p.Cp > C:
         wg = jnp.pad(wg, ((0, 0), (0, 0), (0, 0), (0, p.Cp - C), (0, 0)))
     return dma.pack_weight_tiles(wg, p.weights)
 
 
-def _direct_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, stride: int,
-                   relu: bool, checksum: bool, lrn, pool, step_in: int,
-                   in_rows: int, prefetch: bool, single: bool,
-                   row_parallel: bool):
+def _direct_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, relu: bool,
+                   checksum: bool, lrn, pool, step_in: int, prefetch: bool,
+                   single: bool, row_parallel: bool):
     if checksum:
         sdc_ref, acc_ref, y_ref, wbuf, sem = refs
     else:
         acc_ref, y_ref, wbuf, sem = refs
-    s = stride
     _, Rc, wo, Kb = acc_ref.shape
     ib = pl.program_id(1)
     k = pl.program_id(2)
@@ -215,36 +270,28 @@ def _direct_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, stride: int,
     def _init():
         acc_ref[bi] = jnp.zeros(acc_ref.shape[1:], acc_ref.dtype)
 
-    rows = x_ref[bi, pl.ds(ib * step_in, in_rows)]  # (in_rows, Wp, Cb)
-    _, Wp, Cb = rows.shape
-    r = w.shape[0]
-    acc = jnp.zeros((Rc, wo, Kb), jnp.float32)
+    r, _, Cb, _ = w.shape
+    row0 = ib * step_in
+    acc = jnp.zeros((Rc * wo, Kb), jnp.float32)
     for di in range(r):
-        # conv rows hit by filter row di, still at full input width
-        sub = jax.lax.slice(rows, (di, 0, 0),
-                            (di + s * (Rc - 1) + 1, Wp, Cb), (s, 1, 1))
-        # r width-taps stacked into the contraction dim: one
-        # (Rc*wo, r*Cb) @ (r*Cb, Kb) MXU GEMM per filter row
-        taps = jnp.stack(
-            [jax.lax.slice(sub, (0, dj, 0),
-                           (Rc, dj + s * (wo - 1) + 1, Cb), (1, s, 1))
-             for dj in range(r)], axis=0).astype(jnp.float32)
-        acc += jnp.einsum("jrwc,jck->rwk", taps, w[di])
-    acc_ref[bi] += acc                              # one scratch RMW per step
+        for dj in range(r):
+            # one (Rc*wo, Cb) @ (Cb, Kb) MXU GEMM per filter tap
+            tap = x_ref[bi, pl.ds(row0 + di, Rc), pl.ds(dj, wo), :]
+            acc += jnp.dot(tap.reshape(Rc * wo, Cb).astype(jnp.float32),
+                           w[di, dj], precision=F32_DOT,
+                           preferred_element_type=jnp.float32)
+    acc_ref[bi] += acc.reshape(Rc, wo, Kb)          # one scratch RMW per step
 
     @pl.when(c == nc - 1)
     def _store_kblock():
-        y = acc_ref[bi] + b_ref[0].astype(jnp.float32)
+        y = acc_ref[bi] + b_ref[pl.ds(k, 1), :].astype(jnp.float32)
         if relu:
             y = jnp.maximum(y, 0.0)
-        # channel blocks are group-major contiguous: block k -> offset k*Kb
-        y_ref[bi, :, :, pl.ds(k * Kb, Kb)] = y
+        y_ref[bi, k] = y
 
     @pl.when((c == nc - 1) & (k == nk - 1))
     def _epilogue():
-        out_ref[bi] = fused_epilogue(
-            y_ref[bi], lrn, pool, out_ref.shape[1],
-            out_ref.shape[2]).astype(out_ref.dtype)
+        fused_epilogue(y_ref, out_ref, bi, lrn, pool)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "padding", "relu",
@@ -268,8 +315,8 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
     optional bias ``b (K,)``, fused ``relu``, grouped conv on the
     group-major channel layout, and the in-VMEM ``lrn``/``pool`` epilogue —
     so ``nn.conv.dispatch_conv`` can send *any* ConvSpec here and every
-    AlexNet layer (conv1's 11x11 stride 4 included) runs fully in-VMEM on
-    the ``pallas`` route.
+    AlexNet layer (conv1's 11x11 stride 4 included, as a space-to-depth
+    stride-1 conv) runs fully in-VMEM on the ``pallas`` route.
 
     Weight stream: ``pack_weights(w, plan(...))`` tiles the filters; the
     kernel double-buffers them HBM->VMEM by manual async copy
@@ -301,18 +348,18 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
              pool_row_block=pool_row_block, c_block=c_block,
              k_block=k_block, batch_block=batch_block, checksum=checksum)
     B, H, W, _ = x.shape
-    s, r, g = p.s, p.r, p.g
+    s, g = p.s, p.g
 
-    xg, _ = grouped_channel_pad(x, g, p.Cb)
     # strided convs can leave trailing rows/cols no output window reads —
     # crop them before padding up to the slab extent; a pool with
     # stride > window additionally skips trailing *conv* rows, so the row
     # plan may read fewer rows than the conv extent (Hp < padded H)
-    used_h = min(H, s * (p.out_h - 1) + r - p.ph_lo, p.Hp - p.ph_lo)
-    used_w = min(W, s * (p.out_w - 1) + r - p.pw_lo)
-    xg = xg[:, :used_h, :used_w]
-    xg = jnp.pad(xg, ((0, p.Bp - B), (p.ph_lo, p.Hp - used_h - p.ph_lo),
-                      (p.pw_lo, p.Wp - used_w - p.pw_lo), (0, 0)))
+    used_h = min(H, s * (p.out_h - 1) + p.r_in - p.ph_lo, s * p.Hp - p.ph_lo)
+    used_w = min(W, s * (p.out_w - 1) + p.r_in - p.pw_lo)
+    xg = jnp.pad(x[:, :used_h, :used_w],
+                 ((0, p.Bp - B), (p.ph_lo, s * p.Hp - used_h - p.ph_lo),
+                  (p.pw_lo, s * p.Wp - used_w - p.pw_lo), (0, 0)))
+    xg, _ = grouped_channel_pad(_input_to_depth(xg, p), g, p.Cb)
     w_tiles = dma.resolve_slab(w, w_packed, p.weights,
                                lambda w: pack_weights(w, p))
     bias = jnp.zeros((p.Kfull,), x.dtype) if b is None else b
@@ -320,21 +367,18 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
 
     single = p.weights.n_tiles == 1
     row_par = bool(row_parallel) and not single
-    kernel = functools.partial(_direct_kernel, stride=s, relu=relu,
+    kernel = functools.partial(_direct_kernel, relu=relu,
                                checksum=p.checksum, lrn=lrn,
                                pool=pool, step_in=p.step_in,
-                               in_rows=p.in_rows, prefetch=weight_prefetch,
+                               prefetch=weight_prefetch,
                                single=single, row_parallel=row_par)
     out_specs = [pl.BlockSpec((p.Bb, p.rows_out, p.w_out, p.Kfull),
                               lambda bo, i, k, c, bi: (bo, i, 0, 0))]
     out_shape = [jax.ShapeDtypeStruct(
         (p.Bp, p.npr * p.rows_out, p.w_out, p.Kfull), x.dtype)]
     if p.checksum:
-        # per-(batch, row) ABFT verdict (0 everywhere == clean launch)
-        out_specs.append(pl.BlockSpec((1, 1),
-                                      lambda bo, i, k, c, bi: (bo, i)))
-        out_shape.append(jax.ShapeDtypeStruct((p.Bp // p.Bb, p.npr),
-                                              jnp.int32))
+        out_specs.append(dma.verdict_spec())
+        out_shape.append(dma.verdict_shape(p.Bp // p.Bb, p.npr))
     res = pl.pallas_call(
         kernel,
         grid=(p.Bp // p.Bb, p.npr, g * p.nkb, p.ncb, p.Bb),
@@ -346,19 +390,22 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
             # pipeline (fetched once, resident); a multi-tile stream stays
             # in ANY space and moves by manual double-buffered DMA
             (dma.single_tile_spec(p.weights) if single
-             else pl.BlockSpec(memory_space=pltpu.ANY)),
-            pl.BlockSpec((1, p.Kb), lambda bo, i, k, c, bi: (k, 0)),
+             else pl.BlockSpec(memory_space=pl.ANY)),
+            # the whole (g*nkb, Kb) bias stays resident; the kernel picks
+            # row k (a (1, Kb) block would break the 8-row tiling rule)
+            pl.BlockSpec((g * p.nkb, p.Kb), lambda *_: (0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((p.Bb, p.Rc, p.out_w, p.Kb), jnp.float32),
-            pltpu.VMEM((p.Bb, p.Rc, p.out_w, p.Kfull), jnp.float32),
+            pltpu.VMEM((p.Bb, p.Rc, p.wc, p.Kb), jnp.float32),
+            pltpu.VMEM((p.Bb, g * p.nkb, p.Rc, p.wc, p.Kb), jnp.float32),
             *dma.weight_dma_scratch(p.weights, w_tiles.dtype,
                                     single=single),
         ],
         compiler_params=tpu_compiler_params(
-            *dma.grid_semantics(single, row_par)),
+            *dma.grid_semantics(single, row_par),
+            vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
     )(xg, w_tiles, bg)
 

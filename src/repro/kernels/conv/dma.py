@@ -161,11 +161,23 @@ def verify_tile_checksum(sdc_ref, tile):
 
     @pl.when((k == 0) & (c == 0) & (bi == 0))
     def _init():
-        sdc_ref[0, 0] = 0
+        sdc_ref[...] = jnp.zeros(sdc_ref.shape, sdc_ref.dtype)
 
     @pl.when(bi == 0)
     def _count():
-        sdc_ref[0, 0] += checksum_mismatches(tile)
+        sdc_ref[...] += checksum_mismatches(tile)
+
+
+def verdict_shape(n_batch_blocks: int, n_row_blocks: int):
+    """The per-(batch, row) ABFT verdict output: one int32 per block, in
+    trailing (1, 1) dims so its (1, 1, 1, 1) block spans whole dims."""
+    return jax.ShapeDtypeStruct((n_batch_blocks, n_row_blocks, 1, 1),
+                                jnp.int32)
+
+
+def verdict_spec():
+    """BlockSpec of :func:`verdict_shape` on the shared conv grid."""
+    return pl.BlockSpec((1, 1, 1, 1), lambda bo, i, k, c, bi: (bo, i, 0, 0))
 
 
 def weight_dma_scratch(plan: WeightPlan, dtype, *, single: bool = False):

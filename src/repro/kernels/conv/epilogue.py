@@ -10,14 +10,30 @@ exists exactly once — plus the host-side channel/batch block helpers both
 ``pallas_call`` setups use.
 
 Everything here runs *inside* a kernel (on VMEM-resident arrays) except the
-``*_blocks`` helpers, which are host-side setup.
+``*_blocks`` and ``vmem_limit`` helpers, which are host-side setup.
+
+The full-channel scratch is *chunked* by output-channel block,
+``(Bb, n_kblocks, rows, cols, Kb)``: Mosaic's strided VMEM loads and
+stores (the max-pool windows here, the Winograd tile gather and scatter)
+need a buffer of at most 128 lanes, and every K block is at most that.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-from ...core.winograd import auto_c_block
+from ...core.winograd import auto_c_block, vmem_bytes
+
+# every GEMM of the conv datapath, in the kernels and in the filter
+# transform ahead of them: float32 operands contracted at full float32.  A
+# dot without it runs at the TPU default, which rounds operands to bfloat16
+F32_DOT = jax.lax.Precision.HIGHEST
+
+# scoped-VMEM request bounds: the v5e default scoped limit is 16 MiB of
+# its 128 MiB, and Mosaic keeps in-kernel values on top of the buffers
+_VMEM_FLOOR = 32 * 2 ** 20
+_VMEM_CEILING = 110 * 2 ** 20
 
 
 # ---------------------------------------------------------------------------
@@ -37,49 +53,73 @@ def lrn_banded(yf, lrn):
     band = (jnp.abs(ci - cj) <= half).astype(jnp.float32)
     win = jax.lax.dot_general(
         (yf * yf).reshape(-1, Kf), band, (((1,), (0,)), ((), ())),
+        precision=F32_DOT,
         preferred_element_type=jnp.float32).reshape(yf.shape)
     return yf / jnp.power(lrn.k + lrn.alpha / lrn.n * win, lrn.beta)
 
 
-def maxpool_strided(yf, pool, pr: int, pw: int):
-    """VALID max-pool of (rows, cols, K) via window**2 strided slices."""
+def maxpool_strided(y_ref, idx, pool, pr: int, pw: int):
+    """VALID max-pool of the (rows, cols, Kb) plane ``y_ref[idx]`` via
+    window**2 strided VMEM loads."""
     pwin, ps = pool
-    Kf = yf.shape[-1]
     yp = None
     for di in range(pwin):
         for dj in range(pwin):
-            sl = jax.lax.slice(
-                yf, (di, dj, 0),
-                (di + ps * (pr - 1) + 1, dj + ps * (pw - 1) + 1, Kf),
-                (ps, ps, 1))
+            sl = y_ref[(*idx, pl.ds(di, pr, stride=ps),
+                        pl.ds(dj, pw, stride=ps), slice(None))]
             yp = sl if yp is None else jnp.maximum(yp, sl)
     return yp
 
 
-def fused_epilogue(yf, lrn, pool, pr: int, pw: int):
-    """LRN (or None) then max-pool (or None) on the full-channel VMEM slab.
+def fused_epilogue(y_ref, out_ref, bi, lrn, pool):
+    """LRN (or None) then max-pool (or None) of image slot ``bi``'s
+    chunked full-channel scratch ``y_ref[bi]`` (n_kblocks, rows, cols, Kb),
+    written to ``out_ref[bi]`` (pr, pw, n_kblocks * Kb).
 
-    ``yf`` is (rows, cols, K) f32 with rows >= the rows this grid step owns;
-    returns the (pr, pw, K) block to write (pool) or the first ``pr`` rows
-    (no pool — trailing rows belong to the next step or are padding).
+    LRN windows cross K-block seams, so it runs on the lane-concatenated
+    channels and writes the normalized chunks back; the pool then reads
+    each chunk with strided loads.  Without a pool the first ``pr`` rows
+    are written (trailing rows belong to the next step or are padding).
     """
+    nk, Kb = y_ref.shape[1], y_ref.shape[-1]
+    pr, pw = out_ref.shape[1], out_ref.shape[2]
     if lrn is not None:
+        yf = jnp.concatenate([y_ref[bi, j] for j in range(nk)], axis=-1)
         yf = lrn_banded(yf, lrn)
-    if pool is not None:
-        return maxpool_strided(yf, pool, pr, pw)
-    return yf[:pr]
+        for j in range(nk):
+            y_ref[bi, j] = yf[..., j * Kb:(j + 1) * Kb]
+    for j in range(nk):
+        if pool is not None:
+            blk = maxpool_strided(y_ref, (bi, j), pool, pr, pw)
+        else:
+            blk = y_ref[bi, j, :pr, :pw]
+        out_ref[bi, :, :, j * Kb:(j + 1) * Kb] = blk.astype(out_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
 # host-side block helpers shared by both pallas_call setups
 # ---------------------------------------------------------------------------
 def channel_blocks(C: int, c_block: int | None, hp: int, wp: int,
-                   batch: int = 1, *, dtype_bytes: int = 4) -> int:
-    """Channel block size: explicit, or auto-sized so the whole resident
-    (batch, hp, wp, Cb) input block fits the VMEM slab budget."""
+                   batch: int = 1, *, groups: int = 1,
+                   max_block: int | None = None,
+                   dtype_bytes: int = 4) -> int:
+    """Channel block size: explicit, or auto-sized (lane-legal, padded,
+    double-buffered) so the whole resident (batch, hp, wp, Cb) input block
+    fits the VMEM slab budget — see ``auto_c_block``."""
     if c_block is None:
-        return auto_c_block(hp, wp, C, batch=batch, dtype_bytes=dtype_bytes)
+        return auto_c_block(hp, wp, C, batch=batch, groups=groups,
+                            max_block=max_block, dtype_bytes=dtype_bytes)
     return min(c_block, C)
+
+
+def vmem_limit(pipelined, scratch, dtype_bytes: int = 4) -> int:
+    """Scoped-VMEM bytes to request for one kernel launch: the grid
+    pipeline double-buffers every ``pipelined`` block shape, ``scratch``
+    shapes are allocated once, all padded to the (8, 128) tile; the same
+    again (at least the floor) is left for Mosaic's in-kernel values."""
+    need = (2 * sum(vmem_bytes(s, dtype_bytes) for s in pipelined)
+            + sum(vmem_bytes(s, dtype_bytes) for s in scratch))
+    return int(min(max(2 * need, need + _VMEM_FLOOR), _VMEM_CEILING))
 
 
 def k_blocks(K: int, k_block: int) -> int:

@@ -45,14 +45,15 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.winograd import auto_pool_rows, winograd_transform
+from ...core.winograd import LANES, auto_pool_rows, winograd_transform
 from ..compat import PARALLEL, tpu_compiler_params
 from . import dma
-from .epilogue import batch_blocks, channel_blocks, fused_epilogue, \
-    grouped_channel_pad, k_blocks
+from .epilogue import F32_DOT, batch_blocks, channel_blocks, fused_epilogue, \
+    grouped_channel_pad, k_blocks, vmem_limit
 
 
 # ---------------------------------------------------------------------------
@@ -191,6 +192,26 @@ class WinogradPlan:
                               spatial=(self.n, self.n),
                               checksum=self.checksum)
 
+    @property
+    def vmem_limit_bytes(self) -> int:
+        tile = (1, *self.weights.tile_shape)
+        nk = self.g * self.nkb
+        pipelined = [(self.Bb, self.Hp, self.Wp, self.Cb), (nk, self.Kb)]
+        scratch = [(self.Bb, self.n * self.n, self.Rt * self.tw, self.Kb)]
+        if self.fused:
+            pipelined.append((self.Bb, self.rows_out, self.w_out,
+                              self.Kfull))
+            scratch.append((self.Bb, nk, self.Rt * self.m, self.tw * self.m,
+                            self.Kb))
+        else:
+            pipelined.append((self.Bb, self.Rt * self.m, self.tw * self.m,
+                              self.Kb))
+        if self.weights.n_tiles == 1:
+            pipelined.append(tile)
+        else:
+            scratch.append((2, *tile[1:]))
+        return vmem_limit(pipelined, scratch)
+
 
 def plan(x_shape, w_shape, *, m: int = 4, padding: str = "SAME",
          groups: int = 1, lrn=None, pool=None, row_block: int = 8,
@@ -247,7 +268,8 @@ def plan(x_shape, w_shape, *, m: int = 4, padding: str = "SAME",
     Hp = thp * mm + r - 1
     Wp = tw * mm + r - 1
 
-    Cb = channel_blocks(C, c_block, Hp, Wp, Bb)
+    # the tile planes are stride-m VMEM loads, which take at most 128 lanes
+    Cb = channel_blocks(C, c_block, Hp, Wp, Bb, groups=g, max_block=LANES)
     Cp = C + (-C) % Cb
     if fused:
         # no K padding: zero pad channels inside an LRN window would shadow
@@ -274,36 +296,87 @@ def pack_weights(w, p: WinogradPlan):
     t = winograd_transform(p.m, r)
     wg = jnp.moveaxis(w.reshape(r, r, C, g, K), 3, 0)       # (g, r, r, C, K)
     Gj = jnp.asarray(t.G, jnp.float32)
-    wt = jnp.einsum("in,gnmck,jm->gijck", Gj, wg.astype(jnp.float32), Gj)
+    wt = jnp.einsum("in,gnmck,jm->gijck", Gj, wg.astype(jnp.float32), Gj,
+                    precision=F32_DOT)
     if p.Cp > C or p.Kp > K:
         wt = jnp.pad(wt, ((0, 0), (0, 0), (0, 0), (0, p.Cp - C),
                           (0, p.Kp - K)))
     return dma.pack_weight_tiles(wt, p.weights)
 
 
-def _tiles_from_rows(rows, n: int, mm: int, nr: int, nw: int):
-    """Overlapping n x n tiles from a VMEM row slab via n^2 strided slices:
-    plane (di, dj) holds element (di, dj) of every tile -> (n,n,nr,nw,Cb)."""
-    Cb = rows.shape[-1]
-    return jnp.stack(
-        [jnp.stack(
-            [jax.lax.slice(rows, (di, dj, 0),
-                           (di + (nr - 1) * mm + 1, dj + (nw - 1) * mm + 1,
-                            Cb), (mm, mm, 1))
-             for dj in range(n)], axis=0)
-         for di in range(n)], axis=0).astype(jnp.float32)
+def _coeffs(mat):
+    """Transform matrix rows as float32-rounded Python coefficients.  The
+    least-squares construction leaves the transform's exact zeros at
+    rounding-noise level; those are dropped, so the unrolled transforms
+    below skip them."""
+    mat = np.asarray(mat, np.float64)
+    tol = 1e-9 * np.abs(mat).max()
+    return tuple(tuple(float(np.float32(v)) if abs(v) > tol else 0.0
+                       for v in row) for row in mat)
 
 
-def _conv2d_kernel(x_ref, w_tiles, b_ref, bt_ref, at_ref, out_ref, *refs,
+def _lincomb(coefs, terms):
+    """sum_k coefs[k] * terms[k], unrolled over the non-zero constants —
+    the Winograd transforms as VPU multiply-adds on whole VMEM planes."""
+    acc = None
+    for c, t in zip(coefs, terms):
+        if c == 0.0:
+            continue
+        term = t if c == 1.0 else (-t if c == -1.0 else t * c)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _winograd_gemms(x_ref, acc_ref, v, bi, row0, *, BT, mm: int, nr: int,
+                    nw: int):
+    """Input transform + the n^2 Winograd-domain GEMMs of one grid step.
+
+    Plane (di, dj) — element (di, dj) of every n x n tile of the ``nr`` x
+    ``nw`` tile block starting at slab row ``row0`` — is one stride-m VMEM
+    load of the raw slab (the overlapping tiles never exist anywhere).
+    B^T d B runs separably over those planes, then each of the n^2
+    positions is one (nr*nw, Cb) @ (Cb, Kb) MXU GEMM accumulated into the
+    channel-block scratch (the PE partial sums)."""
+    n = len(BT)
+    Cb = x_ref.shape[-1]
+    d = [[x_ref[bi, pl.ds(row0 + di, nr, stride=mm),
+                pl.ds(dj, nw, stride=mm), :].astype(jnp.float32)
+          for dj in range(n)] for di in range(n)]
+    t = [[_lincomb(BT[i], [d[k][dj] for k in range(n)]) for dj in range(n)]
+         for i in range(n)]
+    for i in range(n):
+        for j in range(n):
+            u = _lincomb(BT[j], t[i]).reshape(nr * nw, Cb)
+            acc_ref[bi, i * n + j] += jnp.dot(
+                u, v[i, j], precision=F32_DOT,
+                preferred_element_type=jnp.float32)
+
+
+def _winograd_outputs(acc_ref, bi, AT):
+    """Output transform A^T M A of the accumulated Winograd-domain block:
+    yields ``(p, q, y)`` with ``y`` (nr*nw, Kb) the conv outputs at
+    position (p, q) of every m x m output tile."""
+    mm, n = len(AT), len(AT[0])
+    acc = [acc_ref[bi, idx] for idx in range(n * n)]
+    for p in range(mm):
+        s = [_lincomb(AT[p], [acc[i * n + j] for i in range(n)])
+             for j in range(n)]
+        for q in range(mm):
+            yield p, q, _lincomb(AT[q], s)
+
+
+def _conv2d_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, BT, AT,
                    relu: bool, checksum: bool, prefetch: bool, single: bool,
                    row_parallel: bool):
     if checksum:
         sdc_ref, acc_ref, wbuf, sem = refs
     else:
         acc_ref, wbuf, sem = refs
-    mm, n = at_ref.shape
-    _, _, _, Rb, tw, Kb = acc_ref.shape
+    mm = len(AT)
+    Rb, tw = out_ref.shape[1] // mm, out_ref.shape[2] // mm
+    Kb = out_ref.shape[-1]
     ib = pl.program_id(1)
+    k = pl.program_id(2)
     c = pl.program_id(3)
     nc = pl.num_programs(3)
     bi = pl.program_id(4)                           # filter-cache image slot
@@ -322,28 +395,24 @@ def _conv2d_kernel(x_ref, w_tiles, b_ref, bt_ref, at_ref, out_ref, *refs,
         acc_ref[bi] = jnp.zeros(acc_ref.shape[1:], acc_ref.dtype)
 
     # raw slab rows for this tile-row block (halo overlap r-1 stays in VMEM)
-    rows = x_ref[bi, pl.ds(ib * Rb * mm, Rb * mm + n - mm)]  # (rows, Wp, Cb)
-    tiles = _tiles_from_rows(rows, n, mm, Rb, tw)
-    BT = bt_ref[...]
-    u = jnp.einsum("in,jm,nmrwc->ijrwc", BT, BT, tiles)
-    # n^2 batched GEMMs on the MXU: (Rb*tw, Cb) @ (Cb, Kb) per (i, j);
-    # accumulated over channel blocks in VMEM scratch (PE partial sums)
-    acc_ref[bi] += jnp.einsum("ijrwc,ijck->ijrwk", u, v)
+    _winograd_gemms(x_ref, acc_ref, v, bi, ib * Rb * mm, BT=BT, mm=mm,
+                    nr=Rb, nw=tw)
 
     @pl.when(c == nc - 1)
     def _epilogue():
-        AT = at_ref[...]
-        y = jnp.einsum("pi,ijrwk->pjrwk", AT, acc_ref[bi])
-        y = jnp.einsum("qj,pjrwk->rpwqk", AT, y)    # (Rb, m, tw, m, Kb)
-        y = y.reshape(Rb * mm, tw * mm, -1) + b_ref[0]
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        out_ref[bi] = y.astype(out_ref.dtype)
+        bias = b_ref[pl.ds(k, 1), :].astype(jnp.float32)
+        for p, q, y in _winograd_outputs(acc_ref, bi, AT):
+            y = y + bias
+            if relu:
+                y = jnp.maximum(y, 0.0)
+            # output (p, q) of every tile: a stride-m store into the block
+            out_ref[bi, pl.ds(p, Rb, stride=mm), pl.ds(q, tw, stride=mm),
+                    :] = y.reshape(Rb, tw, Kb).astype(out_ref.dtype)
 
 
-def _conv2d_fused_kernel(x_ref, w_tiles, b_ref, bt_ref, at_ref, out_ref,
-                         *refs, relu: bool, checksum: bool, lrn,
-                         pool, row_step: int, prefetch: bool, single: bool,
+def _conv2d_fused_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, BT, AT,
+                         relu: bool, checksum: bool, lrn, pool,
+                         row_step: int, prefetch: bool, single: bool,
                          row_parallel: bool):
     """Layer-fused variant: conv + bias + ReLU + LRN + max-pool in VMEM.
 
@@ -358,8 +427,9 @@ def _conv2d_fused_kernel(x_ref, w_tiles, b_ref, bt_ref, at_ref, out_ref,
         sdc_ref, acc_ref, y_ref, wbuf, sem = refs
     else:
         acc_ref, y_ref, wbuf, sem = refs
-    mm, n = at_ref.shape
-    _, _, _, Rt, tw, Kb = acc_ref.shape
+    mm = len(AT)
+    _, _, rows, cols, Kb = y_ref.shape
+    Rt, tw = rows // mm, cols // mm
     ib = pl.program_id(1)
     k = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -379,29 +449,23 @@ def _conv2d_fused_kernel(x_ref, w_tiles, b_ref, bt_ref, at_ref, out_ref,
 
     # raw slab rows for this output-owning block; successive blocks overlap
     # by Rt - row_step tile rows (the output-side pool halo, kept in VMEM)
-    rows = x_ref[bi, pl.ds(ib * row_step * mm, Rt * mm + n - mm)]
-    tiles = _tiles_from_rows(rows, n, mm, Rt, tw)
-    BT = bt_ref[...]
-    u = jnp.einsum("in,jm,nmrwc->ijrwc", BT, BT, tiles)
-    acc_ref[bi] += jnp.einsum("ijrwc,ijck->ijrwk", u, v)
+    _winograd_gemms(x_ref, acc_ref, v, bi, ib * row_step * mm, BT=BT, mm=mm,
+                    nr=Rt, nw=tw)
 
     @pl.when(c == nc - 1)
     def _store_kblock():
-        AT = at_ref[...]
-        y = jnp.einsum("pi,ijrwk->pjrwk", AT, acc_ref[bi])
-        y = jnp.einsum("qj,pjrwk->rpwqk", AT, y)    # (Rt, m, tw, m, Kb)
-        y = y.reshape(Rt * mm, tw * mm, Kb) + b_ref[0]
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        # channel blocks are group-major contiguous, so block k lands at
-        # offset k*Kb of the full concatenated channel dim
-        y_ref[bi, :, :, pl.ds(k * Kb, Kb)] = y
+        bias = b_ref[pl.ds(k, 1), :].astype(jnp.float32)
+        for p, q, y in _winograd_outputs(acc_ref, bi, AT):
+            y = y + bias
+            if relu:
+                y = jnp.maximum(y, 0.0)
+            # channel block k of the full-channel scratch (group-major)
+            y_ref[bi, k, pl.ds(p, Rt, stride=mm), pl.ds(q, tw, stride=mm),
+                  :] = y.reshape(Rt, tw, Kb)
 
     @pl.when((c == nc - 1) & (k == nk - 1))
     def _epilogue():
-        out_ref[bi] = fused_epilogue(
-            y_ref[bi], lrn, pool, out_ref.shape[1],
-            out_ref.shape[2]).astype(out_ref.dtype)
+        fused_epilogue(y_ref, out_ref, bi, lrn, pool)
 
 
 def _conv2d_fused_call(x, w, b, w_packed, *, t, p: WinogradPlan, relu,
@@ -437,7 +501,8 @@ def _conv2d_fused_call(x, w, b, w_packed, *, t, p: WinogradPlan, relu,
 
     single = p.weights.n_tiles == 1
     row_par = bool(row_parallel) and not single
-    kernel = functools.partial(_conv2d_fused_kernel, relu=relu,
+    kernel = functools.partial(_conv2d_fused_kernel, BT=_coeffs(t.BT),
+                               AT=_coeffs(t.AT), relu=relu,
                                checksum=p.checksum, lrn=lrn,
                                pool=pool, row_step=p.row_step,
                                prefetch=weight_prefetch, single=single,
@@ -449,10 +514,8 @@ def _conv2d_fused_call(x, w, b, w_packed, *, t, p: WinogradPlan, relu,
     if p.checksum:
         # per-(batch, row) ABFT verdict: mismatched checksum lanes seen by
         # that block's weight stream (0 everywhere == clean launch)
-        out_specs.append(pl.BlockSpec((1, 1),
-                                      lambda bo, i, k, c, bi: (bo, i)))
-        out_shape.append(jax.ShapeDtypeStruct((p.Bp // p.Bb, p.npr),
-                                              jnp.int32))
+        out_specs.append(dma.verdict_spec())
+        out_shape.append(dma.verdict_shape(p.Bp // p.Bb, p.npr))
     res = pl.pallas_call(
         kernel,
         grid=(p.Bp // p.Bb, p.npr, g * p.nkb, p.ncb, p.Bb),
@@ -464,24 +527,25 @@ def _conv2d_fused_call(x, w, b, w_packed, *, t, p: WinogradPlan, relu,
             # pipeline (fetched once, resident); a multi-tile stream stays
             # in ANY space and moves by manual double-buffered DMA
             (dma.single_tile_spec(p.weights) if single
-             else pl.BlockSpec(memory_space=pltpu.ANY)),
-            pl.BlockSpec((1, p.Kb), lambda bo, i, k, c, bi: (k, 0)),
-            pl.BlockSpec((t.n, t.n), lambda bo, i, k, c, bi: (0, 0)),
-            pl.BlockSpec((t.m, t.n), lambda bo, i, k, c, bi: (0, 0)),
+             else pl.BlockSpec(memory_space=pl.ANY)),
+            # the whole (g*nkb, Kb) bias stays resident; the kernel picks
+            # row k (a (1, Kb) block would break the 8-row tiling rule)
+            pl.BlockSpec((g * p.nkb, p.Kb), lambda *_: (0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((p.Bb, t.n, t.n, p.Rt, p.tw, p.Kb), jnp.float32),
-            pltpu.VMEM((p.Bb, p.Rt * mm, p.tw * mm, p.Kfull), jnp.float32),
+            pltpu.VMEM((p.Bb, t.n * t.n, p.Rt * p.tw, p.Kb), jnp.float32),
+            pltpu.VMEM((p.Bb, g * p.nkb, p.Rt * mm, p.tw * mm, p.Kb),
+                       jnp.float32),
             *dma.weight_dma_scratch(p.weights, w_tiles.dtype,
                                     single=single),
         ],
         compiler_params=tpu_compiler_params(
-            *dma.grid_semantics(single, row_par)),
+            *dma.grid_semantics(single, row_par),
+            vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
-    )(xg, w_tiles, bg, jnp.asarray(t.BT, jnp.float32),
-      jnp.asarray(t.AT, jnp.float32))
+    )(xg, w_tiles, bg)
 
     out = res[0]
     if pool is not None:
@@ -533,9 +597,9 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
     buffers hold whole AlexNet feature-map planes in M20K — one full
     (Hp, Wp, c_block) image plane is VMEM-resident per image slot;
     ``c_block=None`` auto-sizes the channel block so the slab fits the VMEM
-    budget (AlexNet layers get all of C resident — no slab re-fetch over the
-    channel-block reduction), and ``row_block`` tiles the *compute*
-    (tiles/scratch), not input residency (see ``conv2d_hbm_bytes``).
+    budget (at most 128 lanes: the tile planes are stride-m VMEM loads),
+    and ``row_block`` tiles the *compute* (tiles/scratch), not input
+    residency (see ``conv2d_hbm_bytes``).
 
     ABFT (``checksum=True``): the packed slab carries one extra bit-pattern
     checksum row per tile (``dma.append_checksum_row``); the kernel verifies
@@ -574,7 +638,8 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
 
     single = p.weights.n_tiles == 1
     row_par = bool(row_parallel) and not single
-    kernel = functools.partial(_conv2d_kernel, relu=relu,
+    kernel = functools.partial(_conv2d_kernel, BT=_coeffs(t.BT),
+                               AT=_coeffs(t.AT), relu=relu,
                                checksum=p.checksum,
                                prefetch=weight_prefetch, single=single,
                                row_parallel=row_par)
@@ -583,10 +648,8 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
     out_shape = [jax.ShapeDtypeStruct(
         (p.Bp, p.thp * t.m, p.tw * t.m, g * p.Kp), x.dtype)]
     if p.checksum:
-        out_specs.append(pl.BlockSpec((1, 1),
-                                      lambda bo, i, k, c, bi: (bo, i)))
-        out_shape.append(jax.ShapeDtypeStruct((p.Bp // p.Bb, p.npr),
-                                              jnp.int32))
+        out_specs.append(dma.verdict_spec())
+        out_shape.append(dma.verdict_shape(p.Bp // p.Bb, p.npr))
     res = pl.pallas_call(
         kernel,
         grid=(p.Bp // p.Bb, p.npr, g * p.nkb, p.ncb, p.Bb),
@@ -598,23 +661,21 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
             # pipeline (fetched once, resident); a multi-tile stream stays
             # in ANY space and moves by manual double-buffered DMA
             (dma.single_tile_spec(p.weights) if single
-             else pl.BlockSpec(memory_space=pltpu.ANY)),
-            pl.BlockSpec((1, p.Kb), lambda bo, i, k, c, bi: (k, 0)),
-            pl.BlockSpec((t.n, t.n), lambda bo, i, k, c, bi: (0, 0)),
-            pl.BlockSpec((t.m, t.n), lambda bo, i, k, c, bi: (0, 0)),
+             else pl.BlockSpec(memory_space=pl.ANY)),
+            pl.BlockSpec((g * p.nkb, p.Kb), lambda *_: (0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((p.Bb, t.n, t.n, p.Rt, p.tw, p.Kb), jnp.float32),
+            pltpu.VMEM((p.Bb, t.n * t.n, p.Rt * p.tw, p.Kb), jnp.float32),
             *dma.weight_dma_scratch(p.weights, w_tiles.dtype,
                                     single=single),
         ],
         compiler_params=tpu_compiler_params(
-            *dma.grid_semantics(single, row_par)),
+            *dma.grid_semantics(single, row_par),
+            vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
-    )(xg, w_tiles, bg, jnp.asarray(t.BT, jnp.float32),
-      jnp.asarray(t.AT, jnp.float32))
+    )(xg, w_tiles, bg)
 
     y = res[0][:B, :p.out_h, :p.out_w]
     if p.Kp > p.K:

@@ -15,6 +15,7 @@ import dataclasses
 import numpy as np
 
 from ..configs import ASSIGNED, CNN_ARCHS, get_config
+from ..serving.compile_cache import enable_compile_cache
 from ..serving import (CnnEngine, CnnServeConfig, Engine, FaultInjector,
                        FaultSpec, ImageRequest, Request, ServeConfig,
                        Supervisor, SupervisorConfig, WorkerModel,
@@ -244,6 +245,7 @@ def main():
                          "to demonstrate zero-loss failover")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

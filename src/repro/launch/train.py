@@ -13,6 +13,7 @@ import json
 import jax
 
 from ..configs import ASSIGNED, get_config
+from .mesh import make_host_mesh
 from ..parallel import sharding as shlib
 from ..runtime import Trainer, TrainerConfig
 
@@ -39,7 +40,7 @@ def main():
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_host_mesh((d, m), ("data", "model"))
     rules = json.loads(args.rules) if args.rules else None
 
     tcfg = TrainerConfig(steps=args.steps, batch=args.batch,

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core import bfp
-from .compat import axis_size, shard_map
 
 
 def _ring_rs(x, axis_name: str, *, block: int, bits: int):
@@ -26,7 +25,7 @@ def _ring_rs(x, axis_name: str, *, block: int, bits: int):
 
     x: (n * chunk, ...) locally identical-shaped shard view. Returns this
     device's reduced chunk, i.e. chunk index = axis_index."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     d = jax.lax.axis_index(axis_name)
     chunks = x.reshape((n, -1) + x.shape[1:])
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -46,7 +45,7 @@ def _ring_rs(x, axis_name: str, *, block: int, bits: int):
 
 def bfp_psum(x, axis_name: str, *, block: int = 32, bits: int = 8):
     """All-reduce = compressed ring reduce-scatter + compressed all-gather."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     orig_shape = x.shape
@@ -85,13 +84,13 @@ def make_compressed_grad_sync(mesh: Mesh, axis: str = "data", *,
                 s = bfp_psum(g, axis, block=block, bits=bits)
             else:
                 s = jax.lax.psum(g, axis)
-            return s / axis_size(axis)
+            return s / jax.lax.axis_size(axis)
         return jax.tree_util.tree_map(one, grads)
 
     def wrapped(grads):
         spec = jax.tree_util.tree_map(lambda _: P(), grads)
-        return shard_map(sync, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                         check_vma=False)(grads)
+        return jax.shard_map(sync, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec, check_vma=False)(grads)
 
     return wrapped
 

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
 
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
@@ -75,5 +74,5 @@ def pipeline_apply(fn: Callable, stage_params, x, *, mesh: Mesh,
 
     other_axes = [a for a in mesh.axis_names if a != axis]
     in_x_spec = P()      # replicated microbatches (data axis handled outside)
-    return shard_map(run, mesh=mesh, in_specs=(pspec, in_x_spec),
-                     out_specs=P(), check_vma=False)(stage_params, x)
+    return jax.shard_map(run, mesh=mesh, in_specs=(pspec, in_x_spec),
+                         out_specs=P(), check_vma=False)(stage_params, x)

@@ -24,9 +24,7 @@ reproduces that request-to-prediction path in software on top of the shared
   BFP-quantized) are packed exactly once per bucket shape on the host and
   passed to the compiled forward as *jit arguments* (the
   ``PackedConvWeights`` pytree), so the serving graph consumes staged
-  slabs instead of re-packing filters in-trace every call; the staged
-  image buffer is donated to the compiled call where the backend supports
-  buffer donation.
+  slabs instead of re-packing filters in-trace every call.
 * **Double-buffered staging** — host->device image copies are dispatched
   asynchronously up to ``staging_depth`` groups ahead, so the H2D transfer
   of group N+1 overlaps the forward pass of group N — the software analogue
@@ -34,9 +32,17 @@ reproduces that request-to-prediction path in software on top of the shared
   twin of the same idea).  The slot pool is sized ``max_batch *
   staging_depth`` so a full bucket can stage while another computes.
 * **Data parallelism** — with ``data_parallel=True`` the parameters are
-  replicated over a 1-axis device mesh and each bucket's batch axis is
-  sharded across devices (``parallel/sharding.py``); buckets indivisible by
-  the device count fall back to replicated placement.
+  replicated over a 1-axis ``"data"`` mesh and each bucket's batch axis is
+  sharded across devices (``parallel/sharding.py``).  The forward runs under
+  ``shard_map``, so each device runs the whole model — Pallas kernels
+  included, which XLA cannot partition — on its own slice, with slabs
+  packed for that slice; buckets indivisible by the device count run
+  replicated on every device.
+* **Ahead-of-time bucket compiles** — each bucket's forward is lowered and
+  compiled before its first launch (:meth:`CnnEngine.precompile` does the
+  whole ladder up front), outside the fault handling: a forward that does
+  not lower or compile for this device raises out of the engine instead of
+  being retried and degraded to another route.
 
 Fault tolerance (the chaos layer — ``serving/faults.py`` +
 ``serving/health.py``):
@@ -83,8 +89,9 @@ Fault tolerance (the chaos layer — ``serving/faults.py`` +
   Injected via the ``slab.bitflip`` / ``slab.stale`` /
   ``retire.plausible`` fault points.
 
-No Python exception escapes :meth:`step`: injected and real launch/device
-errors are converted into the retry/health machinery above.
+Injected and real launch/device errors never escape :meth:`step`: they
+are converted into the retry/health machinery above.  Only a bucket whose
+forward fails to lower or compile raises.
 
 Request lifecycle: submit() -> queued -> admitted (slots held for one
 bucketed forward) -> staged (H2D in flight) -> computing -> finished
@@ -99,6 +106,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Set, Tuple
@@ -108,6 +116,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import model_for
+from jax.sharding import PartitionSpec as P, SingleDeviceSharding
+
 from ..parallel.sharding import (batch_sharding, data_parallel_mesh,
                                  replicated_sharding)
 from .clock import MONOTONIC, Clock
@@ -181,7 +191,6 @@ class _Group:
     logits: object = None       # device array once compute is dispatched
     sdc: object = None          # device scalar ABFT verdict (sdc_abft only)
     t_launch: float = 0.0       # forward dispatch time (service-time EWMA)
-    first_compile: bool = False  # first time this bucket shape was launched
 
 
 class CnnEngine:
@@ -253,9 +262,8 @@ class CnnEngine:
 
         # pack-once serving forward: weight slabs are packed per bucket
         # shape on the host (_slabs) and enter the compiled graph as jit
-        # *arguments*; the staged image buffer is donated where the
-        # backend implements donation (each buffer is consumed by exactly
-        # one forward).
+        # *arguments*.  The staged image buffer is not donated: no output
+        # has its shape, so XLA could not reuse it.
         mod, ccfg, plans = self.mod, cfg, self.plans
         self._hoist = hasattr(mod, "pack_serving_slabs")
         # SDC defense plane: when the model config arms sdc_abft the
@@ -269,19 +277,18 @@ class CnnEngine:
         self.screen_magnitude = 0
         self._packed: Dict[int, dict] = {}
         self._packed_direct: Dict[int, dict] = {}
-        self._compiled: set = set()
-        self._compiled_direct: set = set()
-        self._apply_direct = None       # built lazily on first degradation
-        donate = (2,) if jax.default_backend() in ("gpu", "tpu") else ()
+        # ahead-of-time compiled forwards per bucket (primary / degraded
+        # route) and the seconds each compile took
+        self.executables: Dict[int, object] = {}
+        self._executables_direct: Dict[int, object] = {}
+        self.compile_seconds: Dict[int, float] = {}
+        self._forwards: Dict[tuple, object] = {}
         if self._hoist:
-            self._apply = jax.jit(
-                lambda p, slabs, x: mod.apply(p, ccfg, x, plans=plans,
-                                              packed=slabs),
-                donate_argnums=donate)
+            self._fn = (lambda p, slabs, x: mod.apply(p, ccfg, x, plans=plans,
+                                                      packed=slabs))
         else:
-            self._apply = jax.jit(
-                (lambda p, x: mod.apply(p, ccfg, x, plans=plans)) if plans
-                else (lambda p, x: mod.apply(p, ccfg, x)))
+            self._fn = ((lambda p, x: mod.apply(p, ccfg, x, plans=plans))
+                        if plans else (lambda p, x: mod.apply(p, ccfg, x)))
         self._staged: Deque[_Group] = deque()
         self._compute: Deque[_Group] = deque()
         # retry holding pen: (ready_time, [reqs]) groups waiting out their
@@ -399,23 +406,98 @@ class CnnEngine:
             f"group of {n} exceeds max_batch={self.buckets[-1]}; "
             f"admission must cap groups at the largest bucket")
 
+    def _shards(self, bucket: int) -> int:
+        """Devices one bucket's batch splits over under data parallelism
+        (1: unsharded, or replicated when the bucket is indivisible)."""
+        n = 1 if self.mesh is None else self.mesh.devices.size
+        return n if bucket % n == 0 else 1
+
+    def _image_sharding(self, bucket: int):
+        if self.mesh is None:
+            return SingleDeviceSharding(jax.devices()[0])
+        if self._shards(bucket) > 1:
+            return batch_sharding(self.mesh, 4)
+        return replicated_sharding(self.mesh)
+
     def _put(self, host: np.ndarray):
         """Async H2D copy (transfer overlaps in-flight compute)."""
-        if self.mesh is None:
-            return jax.device_put(host)
-        if host.shape[0] % self.mesh.devices.size == 0:
-            return jax.device_put(host, batch_sharding(self.mesh, host.ndim))
-        return jax.device_put(host, replicated_sharding(self.mesh))
+        return jax.device_put(host, self._image_sharding(host.shape[0]))
+
+    def _forward(self, bucket: int, degraded: bool):
+        """The jitted forward one bucket launches: the model's own under
+        one device; under data parallelism a ``shard_map`` over
+        ``"data"`` in which every device runs the whole forward on its
+        slice of the batch (or on the whole batch, replicated, when the
+        bucket is indivisible) — XLA cannot partition a Pallas call, so
+        the batch must be split before the kernels see it."""
+        split = self._shards(bucket) > 1
+        key = (degraded, split)
+        if key not in self._forwards:
+            fn = self._direct_fn() if degraded else self._fn
+            if self.mesh is not None:
+                fn = self._shard_mapped(fn, split)
+            self._forwards[key] = jax.jit(fn)
+        return self._forwards[key]
+
+    def _shard_mapped(self, fn, split: bool):
+        x_spec = P("data") if split else P()
+        abft = self._abft
+
+        def body(*args):
+            out = fn(*args)
+            if abft and split:              # one verdict over all slices
+                logits, sdc = out
+                return logits, jax.lax.psum(sdc, "data")
+            return out
+
+        nargs = 3 if self._hoist else 2
+        return jax.shard_map(body, mesh=self.mesh,
+                             in_specs=(P(),) * (nargs - 1) + (x_spec,),
+                             out_specs=(x_spec, P()) if abft else x_spec,
+                             check_vma=False)
+
+    def _executable(self, bucket: int, degraded: bool, images):
+        """The compiled forward for one bucket, lowered and compiled on
+        first use.  Deliberately outside the launch fault handling: a
+        forward that does not lower or compile for this device is a defect
+        of the datapath, not a transient fault, and raises out of the
+        engine rather than being retried and degraded to another route.
+        ``images`` is the staged buffer or a ``ShapeDtypeStruct`` of it."""
+        cache = self._executables_direct if degraded else self.executables
+        if bucket not in cache:
+            args = ((self.params, self._slabs_for(bucket, degraded), images)
+                    if self._hoist else (self.params, images))
+            t0 = time.perf_counter()
+            cache[bucket] = self._forward(bucket, degraded).lower(
+                *args).compile()
+            if not degraded:
+                self.compile_seconds[bucket] = time.perf_counter() - t0
+        return cache[bucket]
+
+    def precompile(self) -> Dict[int, float]:
+        """Lower and compile every bucket of the current ladder before any
+        traffic; returns the compile seconds per bucket.  Raises what the
+        compiler raises."""
+        hw, c = self.cfg.image_size, self.cfg.in_channels
+        for b in self.buckets:
+            self._executable(b, False, jax.ShapeDtypeStruct(
+                (b, hw, hw, c), self._buf_dtype,
+                sharding=self._image_sharding(b)))
+        return {b: self.compile_seconds[b] for b in self.buckets}
+
+    def _slabs_for(self, bucket: int, degraded: bool):
+        return self._slabs_direct(bucket) if degraded else self._slabs(bucket)
 
     def _slabs(self, bucket: int):
         """The hoisted pack-once weight slabs for one bucket shape (packed
         on first use, then reused as jit arguments for every forward of
-        that bucket — the compiled-path twin of the eager WeightStager)."""
+        that bucket — the compiled-path twin of the eager WeightStager).
+        Under data parallelism they are packed for one device's slice."""
         if bucket not in self._packed:
             kw = ({"fingerprint": True} if self.scfg.verify_slabs else {})
-            packed = self.mod.pack_serving_slabs(self.params, self.cfg,
-                                                 bucket, plans=self.plans,
-                                                 **kw)
+            packed = self.mod.pack_serving_slabs(
+                self.params, self.cfg, bucket // self._shards(bucket),
+                plans=self.plans, **kw)
             if self.mesh is not None:
                 packed = jax.device_put(packed,
                                         replicated_sharding(self.mesh))
@@ -500,24 +582,19 @@ class CnnEngine:
                 "bucket": bucket, "reason": kind, "failures": n,
                 "from": self._primary_route, "to": "direct"})
 
-    def _direct_apply(self):
+    def _direct_fn(self):
         """The degraded-bucket forward: same model, direct route (the
-        bit-checked reference datapath), no tuned plans — compiled lazily
-        on the first degradation."""
-        if self._apply_direct is None:
-            mod, cfg_d = self.mod, self._cfg_direct
-            if self._hoist:
-                self._apply_direct = jax.jit(
-                    lambda p, slabs, x: mod.apply(p, cfg_d, x, packed=slabs))
-            else:
-                self._apply_direct = jax.jit(
-                    lambda p, x: mod.apply(p, cfg_d, x))
-        return self._apply_direct
+        bit-checked reference datapath), no tuned plans."""
+        mod, cfg_d = self.mod, self._cfg_direct
+        if self._hoist:
+            return lambda p, slabs, x: mod.apply(p, cfg_d, x, packed=slabs)
+        return lambda p, x: mod.apply(p, cfg_d, x)
 
     def _slabs_direct(self, bucket: int):
         if bucket not in self._packed_direct:
-            packed = self.mod.pack_serving_slabs(self.params,
-                                                 self._cfg_direct, bucket)
+            packed = self.mod.pack_serving_slabs(
+                self.params, self._cfg_direct,
+                bucket // self._shards(bucket))
             if self.mesh is not None:
                 packed = jax.device_put(packed,
                                         replicated_sharding(self.mesh))
@@ -550,7 +627,8 @@ class CnnEngine:
         flat[int(rng.integers(flat.size))] ^= np.uint8(
             1 << int(rng.integers(8)))
         self._packed[bucket] = {
-            **packed, name: dataclasses.replace(pw, data=jnp.asarray(host))}
+            **packed, name: dataclasses.replace(
+                pw, data=jax.device_put(host, pw.data.sharding))}
 
     def _inject_stale(self, bucket: int):
         """``slab.stale`` payload: one layer's cache entry starts serving a
@@ -687,8 +765,6 @@ class CnnEngine:
             return
         g = self._staged.popleft()
         degraded = g.bucket in self._degraded
-        compiled = self._compiled_direct if degraded else self._compiled
-        g.first_compile = g.bucket not in compiled
         # slab chaos (hoisted primary-route path only — that is where a
         # staged slab cache exists to corrupt) + the pre-dispatch
         # fingerprint gate: a corrupted or stale slab never reaches a
@@ -704,6 +780,7 @@ class CnnEngine:
             self.slab_integrity_failures += 1
             self._fail_batch(g, "slab", repack=True)
             return
+        forward = self._executable(g.bucket, degraded, g.images)
         g.t_launch = self.clock.now()
         try:
             if self.faults is not None:
@@ -713,17 +790,12 @@ class CnnEngine:
                     raise TransientLaunchError(
                         "injected transient launch failure "
                         "(RESOURCE_EXHAUSTED)")
-            if degraded:
-                if self._hoist:
-                    g.logits = self._direct_apply()(
-                        self.params, self._slabs_direct(g.bucket), g.images)
-                else:
-                    g.logits = self._direct_apply()(self.params, g.images)
-            elif self._hoist:
-                g.logits = self._apply(self.params, self._slabs(g.bucket),
-                                       g.images)
+            if self._hoist:
+                g.logits = forward(self.params,
+                                   self._slabs_for(g.bucket, degraded),
+                                   g.images)
             else:
-                g.logits = self._apply(self.params, g.images)
+                g.logits = forward(self.params, g.images)
             if self._abft:
                 g.logits, g.sdc = g.logits
         except EngineCrash as e:
@@ -738,7 +810,6 @@ class CnnEngine:
             self._note_datapath_failure(g.bucket, "launch")
             self._requeue_group(g)
             return
-        compiled.add(g.bucket)
         self._compute.append(g)
 
     def _finish_oldest(self):
@@ -816,9 +887,9 @@ class CnnEngine:
         else:
             self.health.record_failure("nonfinite")
             self._note_datapath_failure(g.bucket, "nonfinite")
-        # service-time EWMA feeds load shedding; a first-compile batch
-        # carries the jit trace and would poison the estimate
-        if self.admission is not None and not g.first_compile and n_good:
+        # service-time EWMA feeds load shedding; compiles happen before
+        # t_launch, so every launch is a clean sample
+        if self.admission is not None and n_good:
             self.admission.observe_batch(n_good, now - g.t_launch)
         if self.policy is not None:
             self.policy.maybe_resize()
@@ -833,7 +904,8 @@ class CnnEngine:
         nothing launches except the half-open probe after ``cooldown_ms``,
         and queued work drains via deadline expiry.  No Python exception
         escapes this method for launch/device failures — they feed the
-        retry + health machinery instead."""
+        retry + health machinery instead; a bucket whose forward does not
+        lower or compile raises (:meth:`_executable`)."""
         t0 = self.clock.now()
         self._pump_retries()
         if self.health.state == QUARANTINED:

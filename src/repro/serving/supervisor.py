@@ -25,6 +25,14 @@ accounting contract); nothing is ever silently lost, because a request
 leaves the supervisor's in-flight table only through a retire record,
 an expiry, or a shed — never through a worker death.
 
+One process per chip: a TPU chip belongs to one process, so on a TPU host
+the supervisor gives worker ``k`` chip ``k`` through its spec
+(:func:`~repro.serving.worker.single_chip_env`, applied by the worker
+before its first JAX call) and refuses more workers than the host has
+chips.  It counts the chips' device nodes and never brings up a JAX
+backend itself — the parity oracle runs in a worker.  On a CPU-only run
+(``JAX_PLATFORMS`` without ``tpu``) workers start as plain processes.
+
 Crash-consistent restart: a respawned worker rebuilds from its
 :class:`~repro.serving.worker.WorkerSpec` — params from the newest
 *intact* checkpoint (crc-verified, torn-latest falls back one step),
@@ -33,7 +41,8 @@ replacement serves bit-identical logits to the process it replaced.
 :meth:`Supervisor.verify_bit_parity` closes the loop: every failed-over
 request's served logits must bit-match a jitted direct forward at the
 exact padded bucket shape it was served in (rebuilt from the
-``served_bucket/row/group`` provenance the engine stamps at retire).
+``served_bucket/row/group`` provenance the engine stamps at retire),
+recomputed by a live worker with ``init(seed)`` params.
 
 Chaos is seeded per worker (``derive_seed(seed, worker_name)`` → one
 :class:`~repro.serving.faults.FaultInjector` each): ``worker.crash``
@@ -43,11 +52,12 @@ dying — both bit-reproducible from (seed, specs).
 """
 from __future__ import annotations
 
+import glob
 import multiprocessing as mp
 import os
-import sys
+import socket
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -56,7 +66,7 @@ from .clock import MONOTONIC, Clock
 from .faults import FaultInjector, FaultSpec, derive_seed
 from .health import QUARANTINED, HealthMonitor
 from .scheduler import DrainTimeout, LatencyTracker
-from .worker import WorkerModel, WorkerSpec, worker_main
+from .worker import WorkerModel, WorkerSpec, single_chip_env, worker_main
 
 __all__ = ["Supervisor", "SupervisorConfig", "WorkerDead", "WorkerTimeout",
            "WorkerModel"]
@@ -114,6 +124,28 @@ def _src_root() -> str:
         os.path.abspath(__file__))))
 
 
+def tpu_chip_count() -> Optional[int]:
+    """TPU chips this process may open, or None where JAX will not use a
+    TPU (a CPU-only run: ``JAX_PLATFORMS`` set without ``tpu``).
+
+    Counted as the chips' device nodes (``/dev/accel<n>``, or one VFIO
+    group ``/dev/vfio/<n>`` per chip), so asking brings up no backend and
+    holds no chip.  The PCI bus would over-count in a container that is
+    given only some of its host's chips."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return None
+    nodes = glob.glob("/dev/accel[0-9]*") + [
+        p for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit()]
+    return len(nodes) or None
+
+
+def _free_port() -> int:
+    with socket.socket() as sk:
+        sk.bind(("localhost", 0))
+        return sk.getsockname()[1]
+
+
 class Supervisor:
     """Own N worker processes; route, heartbeat, fail over, account."""
 
@@ -138,11 +170,20 @@ class Supervisor:
         if root not in pp.split(os.pathsep):
             os.environ["PYTHONPATH"] = (root + os.pathsep + pp) if pp else root
 
+        # one chip per worker on a TPU host (respawns reuse the slot's)
+        chips = tpu_chip_count()
+        if chips is not None and self.sup.n_workers > chips:
+            raise ValueError(f"{self.sup.n_workers} workers but only "
+                             f"{chips} TPU chips: a chip serves one "
+                             f"process")
         self.workers: Dict[str, _Handle] = {}
         for k in range(self.sup.n_workers):
             name = f"w{k}"
             spec = WorkerSpec(name=name, models=self.models,
                               ckpt_dir=ckpt_dir, warm=self.sup.warm)
+            if chips is not None:
+                spec = replace(spec, env=tuple(
+                    single_chip_env(k, _free_port()).items()))
             # chaos_workers narrows the blast radius: "kill worker k at
             # opportunity s" schedules (FaultSpec(at=...)) would otherwise
             # fire on every worker at the same pump index
@@ -583,26 +624,20 @@ class Supervisor:
                 "latency": self.latency.percentiles_ms()}
 
     # -- failover bit-parity ------------------------------------------------
-    def verify_bit_parity(self, *, uids: Optional[Sequence[int]] = None,
-                          params: Optional[dict] = None) -> dict:
+    def verify_bit_parity(self, *, uids: Optional[Sequence[int]] = None
+                          ) -> dict:
         """Check served logits against a jitted direct forward at the
         exact padded bucket shape each request was served in (rebuilt
-        from the retire-time provenance).  Defaults to every completed
-        *failed-over* request — the ISSUE's failover contract.
-
-        ``params``: optional {model: pytree}; defaults to ``init(seed)``
-        per model (what an un-checkpointed worker serves).
+        from the retire-time provenance), recomputed by a live worker with
+        ``init(seed)`` params — what an un-checkpointed worker serves, and
+        independent of any checkpoint a worker restored.  This process
+        never runs JAX.  Defaults to every completed *failed-over* request
+        — the failover contract.
         """
-        import jax
-
-        from ..models import model_for
-
-        cfg_of = {m.name: m.cfg for m in self.models}
-        seed_of = {m.name: m.seed for m in self.models}
         if uids is None:
             uids = [u for u in sorted(self.failover_uids)
                     if self.requests[u][1].done]
-        oracles, params = {}, dict(params or {})
+        cfg_of = {m.name: m.cfg for m in self.models}
         checked = mismatched = 0
         bad: List[int] = []
         for uid in uids:
@@ -610,19 +645,12 @@ class Supervisor:
             if not req.done or req.served_bucket is None:
                 continue
             cfg = cfg_of[model]
-            if model not in oracles:
-                mod = model_for(cfg)
-                if model not in params:
-                    params[model] = mod.init(
-                        jax.random.PRNGKey(seed_of[model]), cfg)
-                oracles[model] = jax.jit(
-                    lambda p, x, _mod=mod, _cfg=cfg: _mod.apply(p, _cfg, x))
             buf = np.zeros((req.served_bucket, cfg.image_size,
                             cfg.image_size, cfg.in_channels),
                            np.dtype(getattr(cfg, "dtype", "float32")))
             for i, guid in enumerate(req.served_group):
                 buf[i] = self.requests[guid][1].image
-            ref = np.asarray(oracles[model](params[model], buf))
+            ref = self._reference(model, buf)
             checked += 1
             if not np.array_equal(ref[req.served_row],
                                   np.asarray(req.logits)):
@@ -630,3 +658,20 @@ class Supervisor:
                 bad.append(uid)
         return {"checked": checked, "mismatched": mismatched,
                 "bad_uids": bad}
+
+    def _reference(self, model: str, images: np.ndarray) -> np.ndarray:
+        """The parity oracle's logits for one padded batch, from the first
+        live worker that answers."""
+        for h in self._live():
+            try:
+                rep = self._rpc(h, {"op": "reference", "model": model,
+                                    "images": images},
+                                timeout_s=self.sup.spawn_timeout_s)
+            except WorkerDead as e:
+                self._on_worker_death(h, str(e))
+                continue
+            except WorkerTimeout:
+                h.monitor.record_failure("rpc-timeout")
+                continue
+            return rep["logits"]
+        raise WorkerDead("no live worker to recompute the parity oracle")

@@ -31,6 +31,10 @@ and dropped instead of being matched to the wrong call):
 ``checkpoint``      persist every model's params (per-file crc32 manifest,
                     atomic publish) under ``<ckpt_dir>/<model>/``; reply
                     ``{paths}``
+``reference``       the parity oracle: a jitted direct forward of ``model``
+                    with ``init(seed)`` params — independent of any
+                    checkpoint this worker restored — on the padded batch
+                    ``images``; reply ``{logits}``
 ``stall``           chaos payload (``worker.stall``): sleep ``delay_ms``
                     before replying, so the supervisor's heartbeat
                     deadline trips without the process dying
@@ -46,7 +50,9 @@ autotuner plan cache (``results/plans/``, auto-loaded at engine build),
 so a replacement worker serves bit-identical logits to the one that died.
 
 The worker exits on a closed pipe (supervisor death) — no orphan
-processes hold the device.
+processes hold the device.  On a TPU host the supervisor hands each worker
+:func:`single_chip_env` in its spec; the worker applies it before its first
+JAX call, so its runtime sees one chip of its own.
 """
 from __future__ import annotations
 
@@ -57,7 +63,19 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["WorkerModel", "WorkerSpec", "worker_main"]
+__all__ = ["WorkerModel", "WorkerSpec", "single_chip_env", "worker_main"]
+
+
+def single_chip_env(chip: int, port: int) -> Dict[str, str]:
+    """Environment that confines one process's TPU runtime to ``chip`` of
+    its host: a one-chip, one-process slice with its own coordination
+    ``port``.  libtpu reads it when JAX brings its backend up, and a
+    subset of the host's chips lets one process per chip load it."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
 
 
 @dataclass(frozen=True)
@@ -79,6 +97,7 @@ class WorkerSpec:
     warm: bool = True               # compile every bucket before 'ready'
     slot_budget: Optional[int] = None
     keep_checkpoints: int = 3
+    env: Tuple[Tuple[str, str], ...] = ()   # set before JAX starts
 
 
 @dataclass
@@ -88,6 +107,7 @@ class _WorkerState:
     restored: dict                  # model -> restored step (None = init)
     live: Dict[int, tuple] = field(default_factory=dict)  # uid -> (model, req)
     ckpt_step: int = 0
+    oracles: dict = field(default_factory=dict)  # model -> (forward, params)
 
 
 def _model_ckpt_dir(spec: WorkerSpec, model: str) -> Optional[str]:
@@ -153,6 +173,25 @@ def _retire_batch(st: _WorkerState) -> list:
     return out
 
 
+def _reference(st: _WorkerState, spec: WorkerSpec, model: str, images):
+    """Jitted direct forward of ``model`` on one padded batch — the
+    failover parity oracle, run where a chip is.  Its params come from
+    ``init(seed)``, not from what this worker serves, so a checkpoint
+    restored with wrong values cannot agree with itself."""
+    import jax
+
+    from ..models import model_for
+
+    if model not in st.oracles:
+        wm = next(m for m in spec.models if m.name == model)
+        mod = model_for(wm.cfg)
+        st.oracles[model] = (
+            jax.jit(lambda p, x: mod.apply(p, wm.cfg, x)),
+            mod.init(jax.random.PRNGKey(wm.seed), wm.cfg))
+    fn, params = st.oracles[model]
+    return np.asarray(fn(params, images))
+
+
 def _accounting(st: _WorkerState) -> dict:
     return {name: eng.accounting()
             for name, eng in st.registry.engines.items()}
@@ -160,7 +199,12 @@ def _accounting(st: _WorkerState) -> dict:
 
 def worker_main(conn, spec: WorkerSpec) -> None:
     """Child-process entry point (top-level so ``spawn`` can import it)."""
+    # the chip assignment must be in place before JAX brings a backend up
+    os.environ.update(dict(spec.env))
     from .cnn import ImageRequest
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     try:
         st = _build(spec)
@@ -210,6 +254,9 @@ def worker_main(conn, spec: WorkerSpec) -> None:
                     {"step": st.ckpt_step, "params": p},
                     keep=spec.keep_checkpoints)
             reply.update(paths=paths, step=st.ckpt_step)
+        elif op == "reference":
+            reply.update(logits=_reference(st, spec, msg["model"],
+                                           msg["images"]))
         elif op == "stall":
             time.sleep(msg.get("delay_ms", 0.0) / 1e3)
             reply.update(stalled_ms=msg.get("delay_ms", 0.0))

@@ -172,11 +172,12 @@ def test_arm_slo_on_live_engine(served):
     for r in [ImageRequest(image=im) for im in _images(cfg, 2, seed=3)]:
         eng.submit(r)
     eng.run_until_done()
-    compiled = set(eng._compiled)
+    compiled = dict(eng.executables)
+    assert compiled
     eng.arm_slo(50.0, dynamic_buckets=True, admission=True)
     assert eng.policy is not None and eng.admission is not None
     assert eng.scfg.slo_ms == 50.0
-    assert eng._compiled == compiled        # warm state survives
+    assert eng.executables == compiled      # warm state survives
     assert eng.images_completed == 2
     eng.arm_slo(None)                       # disarm
     assert eng.policy is None and eng.admission is None

@@ -208,3 +208,25 @@ def test_data_parallel_bitmatch_subprocess(served):
                        text=True, timeout=600, env=env)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "OK" in r.stdout
+
+
+def test_lowering_error_raises_out_of_engine(served, monkeypatch):
+    """A forward that does not lower (a kernel the compiler refuses) is a
+    datapath defect: it raises out of the engine and never enters the
+    retry / degradation ladder that exists for runtime faults."""
+    cfg, params, _ = served
+    eng = CnnEngine(cfg, CnnServeConfig(max_batch=2, degrade_threshold=1),
+                    params=params)
+
+    def refused(*args, **kwargs):
+        raise NotImplementedError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(alexnet, "features", refused)
+    eng.submit(ImageRequest(image=_images(cfg, 1)[0]))
+    with pytest.raises(NotImplementedError, match="Mosaic refused"):
+        eng.run_until_done()
+    with pytest.raises(NotImplementedError, match="Mosaic refused"):
+        eng.precompile()
+    assert eng.degradations == [] and eng.batches_failed == 0
+    assert eng.health.state == "healthy"
+    assert eng.executables == {}

@@ -207,6 +207,31 @@ def test_crash_consistent_restart_restores_intact_checkpoint(small,
         assert par["checked"] == 3 and par["mismatched"] == 0, par
 
 
+def test_parity_oracle_catches_a_wrong_restored_leaf(small, tmp_path):
+    """The oracle's params come from init(seed), not from the worker's
+    restored checkpoint: a checkpoint that is intact on disk but holds a
+    wrong leaf value is served, and every served logit then mismatches."""
+    import jax
+
+    from repro import checkpoint as ckpt
+    from repro.models import model_for
+
+    cfg, scfg = small
+    p = model_for(cfg).init(jax.random.PRNGKey(0), cfg)
+    p["fc8"]["b"] = p["fc8"]["b"] + 1.0             # the logits' bias
+    ckpt_dir = str(tmp_path / "ck")
+    ckpt.save(os.path.join(ckpt_dir, "alexnet"), {"step": 1, "params": p})
+    sup = _sup(cfg, scfg, n_workers=1, ckpt_dir=ckpt_dir)
+    with sup:
+        assert sup.workers["w0"].restored == {"alexnet": 1}
+        reqs = [ImageRequest(image=im) for im in _images(cfg, 3)]
+        for r in reqs:
+            assert sup.submit("alexnet", r)
+        _drain_ok(sup, 3)
+        par = sup.verify_bit_parity(uids=[r.uid for r in reqs])
+    assert par["checked"] == 3 and par["mismatched"] == 3, par
+
+
 def test_accounting_invariant_under_mixed_process_chaos(small):
     """Property: the fleet invariant holds across a mixed seeded chaos
     schedule (crashes + stalls) over traffic spanning every bucket
@@ -244,3 +269,69 @@ def test_accounting_invariant_under_mixed_process_chaos(small):
         done = [u for u, (m, r) in sup.requests.items() if r.done]
         par = sup.verify_bit_parity(uids=done)
         assert par["mismatched"] == 0, par
+
+
+def test_tpu_host_gives_each_worker_its_own_chip(small, monkeypatch):
+    """On a TPU host worker k is confined to chip k before its JAX runtime
+    starts, and the supervisor refuses more workers than chips."""
+    from repro.serving import supervisor as sv
+    monkeypatch.setattr(sv, "tpu_chip_count", lambda: 4)
+    cfg, scfg = small
+    sup = _sup(cfg, scfg, n_workers=4)          # built, never started
+    envs = [dict(h.spec.env) for h in sup.workers.values()]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               and e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    with pytest.raises(ValueError, match="only 4 TPU chips"):
+        _sup(cfg, scfg, n_workers=5)
+
+
+def test_cpu_workers_start_without_chip_env(small, monkeypatch):
+    from repro.serving import supervisor as sv
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert sv.tpu_chip_count() is None
+    cfg, scfg = small
+    sup = _sup(cfg, scfg, n_workers=2)
+    assert all(h.spec.env == () for h in sup.workers.values())
+
+
+def test_parent_never_initialises_a_backend():
+    """The supervisor process serves, checks failover parity (recomputed
+    by a worker) and shuts down without ever bringing up a JAX backend —
+    on a TPU host that backend would take a chip from the workers."""
+    import subprocess
+    import sys
+    import textwrap
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = textwrap.dedent("""
+        import dataclasses
+        import numpy as np
+        from jax._src import xla_bridge
+        from repro.configs import get_config
+        from repro.serving import (CnnServeConfig, ImageRequest, Supervisor,
+                                   SupervisorConfig, WorkerModel)
+        cfg = dataclasses.replace(get_config("alexnet").reduced(),
+                                  image_size=35)
+        sup = Supervisor(
+            (WorkerModel("alexnet", cfg, CnnServeConfig(max_batch=2)),),
+            SupervisorConfig(n_workers=1, checkpoint_on_start=False))
+        rng = np.random.default_rng(0)
+        reqs = [ImageRequest(image=rng.standard_normal(
+            (35, 35, 3)).astype(np.float32)) for _ in range(3)]
+        with sup:
+            for r in reqs:
+                sup.submit("alexnet", r)
+            sup.run_until_done(max_steps=2000)
+            par = sup.verify_bit_parity(uids=[r.uid for r in reqs])
+        assert par["checked"] == 3 and par["mismatched"] == 0, par
+        assert not xla_bridge.backends_are_initialized()
+        print("OK")
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    assert "OK" in r.stdout
