@@ -35,15 +35,13 @@ resident; both modes then expose the same warmup tile.)
         [--route {auto,direct,winograd,pallas}] [--prefetch {on,off}]
         [--batch N] [--batch-block N] [--k-block N] [--check]
         [--image-size N] [--out BENCH_fused_pipeline.json]
-        [--autotune] [--autotune-budget N] [--trace DIR]
+        [--autotune] [--autotune-budget N]
 
 ``--autotune`` additionally runs the measured per-layer autotuner
 (``core/autotune.py``) over the same config — enumerating the real launch
 knobs, timing each candidate through dispatch_conv, and reporting
 default-vs-tuned wall-clock per layer (the ``autotune`` artifact
-section).  ``--trace DIR`` wraps the measured region in a JAX profiler
-trace (viewable in TensorBoard/Perfetto) so kernel-level timelines sit
-next to the wall-clock numbers.
+section).
 
 ``--check`` exits nonzero unless (a) every Pallas-resolved layer models
 fused bytes strictly below unfused and no layer models fused above
@@ -282,9 +280,6 @@ def main(argv=None):
                          "wall-clock (core/autotune.py)")
     ap.add_argument("--autotune-budget", type=int, default=8,
                     help="max measured candidates per layer for --autotune")
-    ap.add_argument("--trace", default=None, metavar="DIR",
-                    help="capture a JAX profiler trace of the measured "
-                         "region into DIR")
     ap.add_argument("--check", action="store_true",
                     help="exit 1 unless every pallas layer models strictly "
                          "lower fused HBM bytes than unfused AND prefetch-"
@@ -303,8 +298,6 @@ def main(argv=None):
     prefetch = args.prefetch == "on"
     cfg = dataclasses.replace(cfg, weight_prefetch=prefetch)
 
-    if args.trace:
-        jax.profiler.start_trace(args.trace)
     rows = layer_rows(cfg, batch=args.batch, batch_block=args.batch_block,
                       k_block=args.k_block, prefetch=prefetch)
     tune = None
@@ -312,8 +305,6 @@ def main(argv=None):
         from repro.core.autotune import autotune_alexnet
         tune = autotune_alexnet(cfg, args.batch,
                                 max_candidates=args.autotune_budget)
-    if args.trace:
-        jax.profiler.stop_trace()
     net = network_summary(rows, prefetch=prefetch)
     emit([{"name": f"fused_pipeline/{r['layer']}",
            "us_per_call": r["fused_us"],

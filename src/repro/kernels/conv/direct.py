@@ -300,7 +300,7 @@ def _direct_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, relu: bool,
                                              "c_block", "k_block",
                                              "batch_block", "weight_prefetch",
                                              "row_parallel", "checksum",
-                                             "interpret"))
+                                             "interpret", "name"))
 def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
                   padding: str = "SAME", relu: bool = False, groups: int = 1,
                   lrn=None, pool=None, row_block: int = 8,
@@ -308,7 +308,7 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
                   c_block: int | None = None, k_block: int = 128,
                   batch_block: int = 8, weight_prefetch: bool = True,
                   row_parallel: bool = False, checksum: bool = False,
-                  interpret: bool = True):
+                  interpret: bool = True, name: str | None = None):
     """x (B,H,W,C); w (r,r,C//groups,K); any r/stride/groups, fused layer.
 
     Same contract as the Winograd kernel (``winograd.conv2d_winograd``):
@@ -342,6 +342,9 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
     DMA slot swap and the call returns ``(y, verdict)`` — verdict 0 means
     every tile streamed intact.  Clean armed output is bit-identical to
     unarmed (the GEMMs read the same Cb rows either way).
+
+    ``name`` names the ``pallas_call``, and so the kernel's HLO instruction
+    and its device op in a profile (``conv1_direct``).
     """
     p = plan(x.shape, w.shape, stride=stride, padding=padding, pool=pool,
              groups=groups, row_block=row_block,
@@ -407,6 +410,7 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
             *dma.grid_semantics(single, row_par),
             vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
+        name=name,
     )(xg, w_tiles, bg)
 
     out = res[0]

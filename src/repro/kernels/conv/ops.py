@@ -81,7 +81,7 @@ def conv2d(x, w, b=None, w_packed=None, *, m: int = 4, padding: str = "SAME",
            k_block: int = 128, batch_block: int = 8,
            weight_prefetch: bool = True, row_parallel: bool = False,
            checksum: bool = False, pallas: bool = True,
-           interpret: bool | None = None):
+           interpret: bool | None = None, name: str | None = None):
     """Fused stride-1 Winograd conv layer: bias, ReLU, groups, LRN, pool.
 
     Both routes share one signature so they stay numerically
@@ -97,7 +97,7 @@ def conv2d(x, w, b=None, w_packed=None, *, m: int = 4, padding: str = "SAME",
     ``checksum=True`` arms the ABFT weight-stream verification and both
     routes return ``(y, verdict)`` — the jnp route has no DMA stream to
     corrupt, so its verdict is the constant 0 (the contract stays uniform
-    for ``nn.conv.dispatch_conv``).
+    for ``nn.conv.dispatch_conv``).  ``name`` names the Pallas kernel.
     """
     if pallas:
         return _k.conv2d_winograd(x, w, b, w_packed, m=m, padding=padding,
@@ -109,7 +109,7 @@ def conv2d(x, w, b=None, w_packed=None, *, m: int = 4, padding: str = "SAME",
                                   weight_prefetch=weight_prefetch,
                                   row_parallel=row_parallel,
                                   checksum=checksum,
-                                  interpret=_interp(interpret))
+                                  interpret=_interp(interpret), name=name)
     y = wg.conv2d_winograd(x, w, b, m=m, padding=padding, relu=relu,
                            groups=groups, lrn=lrn, pool=pool)
     return (y, jnp.zeros((), jnp.int32)) if checksum else y
@@ -122,7 +122,7 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
                   batch_block: int = 8,
                   weight_prefetch: bool = True, row_parallel: bool = False,
                   checksum: bool = False, pallas: bool = True,
-                  interpret: bool | None = None):
+                  interpret: bool | None = None, name: str | None = None):
     """Fused direct conv layer for any kernel/stride geometry.
 
     ``pallas=True`` runs the strided stream-buffered kernel (``direct.py``)
@@ -130,6 +130,7 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
     ``pallas=False`` is the ``lax.conv_general_dilated`` oracle with the
     same fused-layer signature (``ref.conv2d_ref``).  ``checksum=True``
     returns ``(y, verdict)`` on both routes (constant 0 off-Pallas).
+    ``name`` names the Pallas kernel.
     """
     if pallas:
         return _d.conv2d_direct(x, w, b, w_packed, stride=stride,
@@ -141,7 +142,7 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
                                 weight_prefetch=weight_prefetch,
                                 row_parallel=row_parallel,
                                 checksum=checksum,
-                                interpret=_interp(interpret))
+                                interpret=_interp(interpret), name=name)
     y = conv2d_ref(x, w, b, stride=stride, padding=padding, groups=groups,
                    relu=relu, lrn=lrn, pool=pool)
     return (y, jnp.zeros((), jnp.int32)) if checksum else y

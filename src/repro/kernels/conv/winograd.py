@@ -469,7 +469,8 @@ def _conv2d_fused_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, BT, AT,
 
 
 def _conv2d_fused_call(x, w, b, w_packed, *, t, p: WinogradPlan, relu,
-                       lrn, pool, weight_prefetch, row_parallel, interpret):
+                       lrn, pool, weight_prefetch, row_parallel, interpret,
+                       name):
     """pallas_call setup for the layer-fused kernel (lrn and/or pool set).
 
     Grid (B/Bb, pooled-row blocks, g*K blocks, C blocks, Bb): groups move
@@ -545,6 +546,7 @@ def _conv2d_fused_call(x, w, b, w_packed, *, t, p: WinogradPlan, relu,
             *dma.grid_semantics(single, row_par),
             vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
+        name=name,
     )(xg, w_tiles, bg)
 
     out = res[0]
@@ -560,7 +562,7 @@ def _conv2d_fused_call(x, w, b, w_packed, *, t, p: WinogradPlan, relu,
                                              "c_block", "k_block",
                                              "pool_row_block", "batch_block",
                                              "weight_prefetch", "row_parallel",
-                                             "checksum", "interpret"))
+                                             "checksum", "interpret", "name"))
 def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
                     padding: str = "SAME", relu: bool = False,
                     groups: int = 1, lrn=None, pool=None, row_block: int = 8,
@@ -568,7 +570,7 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
                     c_block: int | None = None, k_block: int = 128,
                     batch_block: int = 8, weight_prefetch: bool = True,
                     row_parallel: bool = False, checksum: bool = False,
-                    interpret: bool = True):
+                    interpret: bool = True, name: str | None = None):
     """x (B,H,W,C); w (r,r,C//groups,K); stride-1 conv via F(m,r) x F(m,r).
 
     Fused pipeline: raw (halo-padded) feature map slabs stream HBM->VMEM via
@@ -607,6 +609,9 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
     ``(y, verdict)`` — verdict 0 means every tile streamed intact, > 0
     counts mismatched checksum lanes.  The GEMMs consume the same Cb rows
     either way, so a clean armed launch is bit-identical to unarmed.
+
+    ``name`` names the ``pallas_call``, and so the kernel's HLO instruction
+    and its device op in a profile (``conv3_winograd``).
     """
     r = w.shape[0]
     t = winograd_transform(m, r)
@@ -619,7 +624,7 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
                                   lrn=lrn, pool=pool,
                                   weight_prefetch=weight_prefetch,
                                   row_parallel=row_parallel,
-                                  interpret=interpret)
+                                  interpret=interpret, name=name)
     B, H, W, _ = x.shape
     g = p.g
 
@@ -675,6 +680,7 @@ def conv2d_winograd(x, w, b=None, w_packed=None, *, m: int = 4,
             *dma.grid_semantics(single, row_par),
             vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
+        name=name,
     )(xg, w_tiles, bg)
 
     y = res[0][:B, :p.out_h, :p.out_w]

@@ -271,8 +271,10 @@ def features(params, cfg: AlexNetConfig, images, *, stager=None, plans=None,
             plan = plans.get(f"conv{i + 1}")
             kw = ({"plan": plan} if plan is not None
                   else {"weight_prefetch": cfg.weight_prefetch})
-            x = dispatch_conv(spec, x, p["w"], p["b"], abft=abft,
-                              w_packed=packed.get(f"conv{i + 1}"), **kw)
+            with jax.named_scope(f"conv{i + 1}"):
+                x = dispatch_conv(spec, x, p["w"], p["b"], abft=abft,
+                                  w_packed=packed.get(f"conv{i + 1}"),
+                                  name=f"conv{i + 1}", **kw)
             if abft:
                 x, v = x
                 sdc = sdc + v
@@ -327,8 +329,10 @@ def features(params, cfg: AlexNetConfig, images, *, stager=None, plans=None,
         # part of the measured winner); untuned layers keep the config's
         kw = ({"plan": plan} if plan is not None
               else {"weight_prefetch": cfg.weight_prefetch})
-        x = dispatch_conv(spec, x, p["w"], p["b"], w_packed=stage(i),
-                          abft=abft, prefetch_next=nxt, **kw)
+        with jax.named_scope(f"conv{i+1}"):
+            x = dispatch_conv(spec, x, p["w"], p["b"], w_packed=stage(i),
+                              abft=abft, prefetch_next=nxt,
+                              name=f"conv{i+1}", **kw)
         if abft:
             x, v = x
             sdc = sdc + v
@@ -352,18 +356,19 @@ def classifier(params, cfg: AlexNetConfig, feats, *, stager=None,
     n_fc = len(cfg.fc_dims)
     for j in range(n_fc):
         p = params[f"fc{j+6}"]
-        if cfg.fc_bfp:
-            if j == 0 and packed is not None:
-                q = packed.get("fc6")
+        with jax.named_scope(f"fc{j+6}"):
+            if cfg.fc_bfp:
+                if j == 0 and packed is not None:
+                    q = packed.get("fc6")
+                else:
+                    q = (stager.get("fc6")
+                         if (j == 0 and stager is not None) else None)
+                x = (bfp_linear(x, p["w"], quantized=q)
+                     + p["b"].astype(jnp.float32)).astype(x.dtype)
             else:
-                q = (stager.get("fc6")
-                     if (j == 0 and stager is not None) else None)
-            x = (bfp_linear(x, p["w"], quantized=q)
-                 + p["b"].astype(jnp.float32)).astype(x.dtype)
-        else:
-            x = x @ p["w"].astype(x.dtype) + p["b"].astype(x.dtype)
-        if j < n_fc - 1:
-            x = jax.nn.relu(x)
+                x = x @ p["w"].astype(x.dtype) + p["b"].astype(x.dtype)
+            if j < n_fc - 1:
+                x = jax.nn.relu(x)
     return x
 
 

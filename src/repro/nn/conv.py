@@ -459,7 +459,8 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
                   plan: ConvPlan | None = None, weight_prefetch=UNSET,
                   k_block=UNSET, batch_block=UNSET, c_block=UNSET,
                   pool_row_block=UNSET, row_parallel=UNSET,
-                  abft: bool = False, prefetch_next=None):
+                  abft: bool = False, prefetch_next=None,
+                  name: str | None = None):
     """Run one conv layer per its spec.  x (B,H,W,C), w (k,k,C//g,K), b (K,).
 
     Grouped convs are batched (``feature_group_count`` on the direct route,
@@ -493,6 +494,10 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
     stream to corrupt, so their verdict is the constant 0.  The ``y``
     values are bit-identical to the unarmed call (the GEMMs consume the
     slab minus its checksum row).
+
+    ``name`` is the layer's key (``conv3``): a Pallas kernel it launches is
+    named ``<name>_<kernel>`` (``conv3_winograd``, ``conv1_direct``), the
+    name its device op carries in a profile.
     """
     assert w.shape[0] == w.shape[1] == spec.kernel, (w.shape, spec.kernel)
     knobs = plan_knobs(plan, batch_block=batch_block, k_block=k_block,
@@ -511,6 +516,8 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
     pool = ((spec.pool_window, spec.pool_stride)
             if spec.fuse_pool and not defer_bias else None)
     kernel = resolve_kernel(spec, in_hw=(x.shape[1], x.shape[2]))
+    kname = (f"{name}_{kernel.removeprefix('pallas-')}"
+             if name and kernel.startswith("pallas") else None)
 
     slab = None
     if w_packed is not None and kernel.startswith("pallas"):
@@ -542,7 +549,8 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
                           batch_block=knobs.batch_block,
                           weight_prefetch=knobs.weight_prefetch,
                           row_parallel=knobs.row_parallel,
-                          checksum=abft, pallas=True, interpret=interpret)
+                          checksum=abft, pallas=True, interpret=interpret,
+                          name=kname)
     elif kernel == "pallas-direct":
         y = pallas_conv2d_direct(x, w, bias, slab, stride=spec.stride,
                                  padding=spec.padding, relu=relu,
@@ -554,7 +562,7 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
                                  weight_prefetch=knobs.weight_prefetch,
                                  row_parallel=knobs.row_parallel,
                                  checksum=abft, pallas=True,
-                                 interpret=interpret)
+                                 interpret=interpret, name=kname)
     else:  # winograd (pure-jnp, differentiable)
         y = conv2d_winograd(x, w, bias, m=spec.winograd_m,
                             padding=spec.padding, relu=relu,

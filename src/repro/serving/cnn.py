@@ -93,6 +93,17 @@ Injected and real launch/device errors never escape :meth:`step`: they
 are converted into the retry/health machinery above.  Only a bucket whose
 forward fails to lower or compile raises.
 
+Profiler spans (``jax.profiler.TraceAnnotation``, on the profiler's clock
+with the device ops; constant names, nested as listed): ``cnn.step`` is
+one :meth:`CnnEngine.step`; inside it ``cnn.stage`` (admission and the
+host buffer) holds one ``cnn.put`` per group (the H2D ``device_put``,
+with ``batch=<n>`` and each request's queue wait in ``queue_wait_us``),
+then ``cnn.launch`` (``batch``, ``bucket``: executable lookup and the
+forward call), ``cnn.fetch`` (``batch``: the blocking fetch of the
+logits and the ABFT verdict) and ``cnn.retire`` (screen, bookkeeping).
+``cnn.compile`` (``bucket``) wraps each bucket's lower-and-compile.  With
+no profiler running a span costs about a microsecond.
+
 Request lifecycle: submit() -> queued -> admitted (slots held for one
 bucketed forward) -> staged (H2D in flight) -> computing -> finished
 (logits + argmax label on the request), with shed / expired as the
@@ -114,10 +125,10 @@ from typing import Deque, Dict, List, Optional, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from ..models import model_for
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P, SingleDeviceSharding
 
+from ..models import model_for
 from ..parallel.sharding import (batch_sharding, data_parallel_mesh,
                                  replicated_sharding)
 from .clock import MONOTONIC, Clock
@@ -170,6 +181,7 @@ class ImageRequest:
     expired: bool = False       # deadline or retry budget exhausted
     expire_reason: Optional[str] = None   # "deadline" | "retries"
     t_submit: float = 0.0
+    t_admit: float = 0.0        # admitted into the group that serves it
     t_done: float = 0.0
     # serving provenance (set at retirement): the padded bucket shape this
     # request was served at, its row in that batch, and the uids of every
@@ -191,6 +203,7 @@ class _Group:
     logits: object = None       # device array once compute is dispatched
     sdc: object = None          # device scalar ABFT verdict (sdc_abft only)
     t_launch: float = 0.0       # forward dispatch time (service-time EWMA)
+    seq: int = 0                # batch number, on its put/launch/fetch spans
 
 
 class CnnEngine:
@@ -291,6 +304,7 @@ class CnnEngine:
                         if plans else (lambda p, x: mod.apply(p, ccfg, x)))
         self._staged: Deque[_Group] = deque()
         self._compute: Deque[_Group] = deque()
+        self._batch_seq = itertools.count()
         # retry holding pen: (ready_time, [reqs]) groups waiting out their
         # exponential backoff before re-queueing at the queue front
         self._retry: List[Tuple[float, List[ImageRequest]]] = []
@@ -468,8 +482,9 @@ class CnnEngine:
             args = ((self.params, self._slabs_for(bucket, degraded), images)
                     if self._hoist else (self.params, images))
             t0 = time.perf_counter()
-            cache[bucket] = self._forward(bucket, degraded).lower(
-                *args).compile()
+            with TraceAnnotation("cnn.compile", bucket=bucket):
+                cache[bucket] = self._forward(bucket, degraded).lower(
+                    *args).compile()
             if not degraded:
                 self.compile_seconds[bucket] = time.perf_counter() - t0
         return cache[bucket]
@@ -727,35 +742,49 @@ class CnnEngine:
     def _stage(self):
         """Admit queued requests into free slots and start their H2D copies.
         Requests already past their deadline at admission retire as
-        expired instead of burning a forward."""
-        while (self.sched.queue and
-               len(self._staged) + len(self._compute) < self.scfg.staging_depth):
-            group = self.sched.admit(limit=self.scfg.max_batch)
-            if not group:
-                break                                   # no free slots
-            now = self.clock.now()
-            slots, reqs = [], []
-            for s, r in group:
-                if self._is_expired(r, now):
-                    self.sched.release(s)
-                    self._retire_expired(r, "deadline")
-                else:
-                    slots.append(s)
-                    reqs.append(r)
-            if not reqs:
-                continue
-            if self.policy is not None:
-                self.policy.observe_admit(len(reqs))
-            bucket = self.bucket_for(len(reqs))
-            h, w, c = reqs[0].image.shape
-            buf = np.zeros((bucket, h, w, c), self._buf_dtype)
-            for i, r in enumerate(reqs):
-                buf[i] = r.image
-            if self.faults is not None and self.faults.fire("stage.corrupt"):
-                # corrupt only the staged copy — req.image stays pristine,
-                # so the retry after the finiteness screen re-stages clean
-                buf[0] = np.nan
-            self._staged.append(_Group(slots, reqs, bucket, self._put(buf)))
+        expired instead of burning a forward.  Each admitted request is
+        stamped ``t_admit``; the group's ``cnn.put`` span carries its batch
+        number and every request's queue wait in microseconds."""
+        with TraceAnnotation("cnn.stage"):
+            while (self.sched.queue
+                   and len(self._staged) + len(self._compute)
+                   < self.scfg.staging_depth):
+                group = self.sched.admit(limit=self.scfg.max_batch)
+                if not group:
+                    break                                   # no free slots
+                now = self.clock.now()
+                slots, reqs = [], []
+                for s, r in group:
+                    if self._is_expired(r, now):
+                        self.sched.release(s)
+                        self._retire_expired(r, "deadline")
+                    else:
+                        r.t_admit = now
+                        slots.append(s)
+                        reqs.append(r)
+                if not reqs:
+                    continue
+                if self.policy is not None:
+                    self.policy.observe_admit(len(reqs))
+                bucket = self.bucket_for(len(reqs))
+                h, w, c = reqs[0].image.shape
+                buf = np.zeros((bucket, h, w, c), self._buf_dtype)
+                for i, r in enumerate(reqs):
+                    buf[i] = r.image
+                if (self.faults is not None
+                        and self.faults.fire("stage.corrupt")):
+                    # corrupt only the staged copy — req.image stays
+                    # pristine, so the retry after the finiteness screen
+                    # re-stages clean
+                    buf[0] = np.nan
+                seq = next(self._batch_seq)
+                waits = " ".join(str(round((now - r.t_submit) * 1e6))
+                                 for r in reqs)
+                with TraceAnnotation("cnn.put", batch=seq,
+                                     queue_wait_us=waits):
+                    images = self._put(buf)
+                self._staged.append(_Group(slots, reqs, bucket, images,
+                                           seq=seq))
 
     def _launch(self):
         """Dispatch the forward pass for the oldest staged group (async).
@@ -764,6 +793,10 @@ class CnnEngine:
         if not self._staged:
             return
         g = self._staged.popleft()
+        with TraceAnnotation("cnn.launch", batch=g.seq, bucket=g.bucket):
+            self._dispatch(g)
+
+    def _dispatch(self, g: _Group):
         degraded = g.bucket in self._degraded
         # slab chaos (hoisted primary-route path only — that is where a
         # staged slab cache exists to corrupt) + the pre-dispatch
@@ -813,17 +846,25 @@ class CnnEngine:
         self._compute.append(g)
 
     def _finish_oldest(self):
-        """Block on the oldest computed group and retire its requests.
-        Retired logits pass the sampled finiteness screen; bad rows retry
-        (never served), clean rows retire normally."""
+        """Block on the oldest computed group's logits (and its ABFT
+        verdict, when armed), then retire its requests."""
         if not self._compute:
             return
         g = self._compute.popleft()
-        try:
-            logits = np.asarray(jax.device_get(g.logits))[: len(g.reqs)]
-        except Exception:       # async device error surfaces at fetch
-            self._fail_batch(g, "device")
-            return
+        with TraceAnnotation("cnn.fetch", batch=g.seq):
+            try:
+                logits = np.asarray(jax.device_get(g.logits))[: len(g.reqs)]
+            except Exception:       # async device error surfaces at fetch
+                self._fail_batch(g, "device")
+                return
+            sdc = (int(np.asarray(jax.device_get(g.sdc)))
+                   if self._abft and g.sdc is not None else 0)
+        with TraceAnnotation("cnn.retire"):
+            self._retire(g, logits, sdc)
+
+    def _retire(self, g: _Group, logits: np.ndarray, sdc: int):
+        """Retired logits pass the sampled finiteness screen; bad rows retry
+        (never served), clean rows retire normally."""
         # ABFT verdict gate: a positive in-kernel checksum mismatch count
         # means the staged filter bits changed between pack and the DMA
         # stream — the whole batch is tainted and is *never served*.  The
@@ -832,11 +873,10 @@ class CnnEngine:
         # the same retry/health/degradation machinery as any datapath
         # failure.  This runs before any retire-stage chaos: the verdict
         # belongs to the forward that computed these logits.
-        if self._abft and g.sdc is not None:
-            if int(np.asarray(jax.device_get(g.sdc))) > 0:
-                self.sdc_detections += 1
-                self._fail_batch(g, "sdc", repack=True)
-                return
+        if sdc > 0:
+            self.sdc_detections += 1
+            self._fail_batch(g, "sdc", repack=True)
+            return
         if self.faults is not None:
             spec = self.faults.fire("retire.latency")
             if spec is not None and spec.delay_ms:
@@ -907,22 +947,23 @@ class CnnEngine:
         retry + health machinery instead; a bucket whose forward does not
         lower or compile raises (:meth:`_executable`)."""
         t0 = self.clock.now()
-        self._pump_retries()
-        if self.health.state == QUARANTINED:
-            self._quarantine_purge()
-            if (self.sched.queue
-                    and len(self._staged) + len(self._compute)
-                    < self.scfg.staging_depth
-                    and self.health.allow_launch()):
+        with TraceAnnotation("cnn.step"):
+            self._pump_retries()
+            if self.health.state == QUARANTINED:
+                self._quarantine_purge()
+                if (self.sched.queue
+                        and len(self._staged) + len(self._compute)
+                        < self.scfg.staging_depth
+                        and self.health.allow_launch()):
+                    self._stage()
+                    if self._staged:
+                        self._launch()              # the half-open probe
+                    else:
+                        self.health.cancel_probe()  # nothing admissible
+            else:
                 self._stage()
-                if self._staged:
-                    self._launch()              # the half-open probe
-                else:
-                    self.health.cancel_probe()  # nothing admissible
-        else:
-            self._stage()
-            self._launch()
-        self._finish_oldest()
+                self._launch()
+            self._finish_oldest()
         self._t_serve += self.clock.now() - t0
 
     @property

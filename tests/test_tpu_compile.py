@@ -5,10 +5,11 @@ that break the (8, 128) tiling, strided value slices, scoped VMEM overuse.
 So each conv layer of the full 227 px config is lowered and compiled on
 the Pallas route for one chip of a described ``v5e:2x2`` topology (no
 chip attached), at batch 1 and at batch 8, and must contain its Pallas
-kernel.  The topology is described inside a fixture, in this one file:
+kernel, named by its layer (``conv3_winograd``).  The topology is described inside a fixture, in this one file:
 only one process at a time may load the TPU compiler's library.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +76,11 @@ def test_conv_layer_compiles_for_v5e(layer, batch, one_chip,
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     fwd = jax.jit(lambda x, w, b: dispatch_conv(spec, x, w, b,
-                                                interpret=False))
+                                                interpret=False, name=name))
     compiled = fwd.lower(sds((batch, *in_shape)), sds(w_shape),
                          sds((w_shape[-1],))).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+    # the kernel's instruction, and so its device op, is named by its layer
+    assert re.search(rf'%{name}_(direct|winograd)(\.\d+)? = [^\n]*'
+                     r'custom_call_target="tpu_custom_call"',
+                     compiled.as_text()), name
