@@ -140,6 +140,20 @@ from .scheduler import DrainTimeout, LatencyTracker, SlotScheduler
 __all__ = ["CnnEngine", "CnnServeConfig", "ImageRequest", "bucket_sizes"]
 
 
+def _copy_image(dst: np.ndarray, img: np.ndarray):
+    """``dst[...] = img`` for one ``(H, W, C)`` image into the C-ordered
+    staging buffer.  numpy runs its inner loop along the destination's
+    fastest axis, C, so an image whose channels are planes (transposed
+    from CHW, or fetched from a device in its layout) would cost one loop
+    per pixel; it is copied a plane at a time instead, W innermost."""
+    img = np.asarray(img)
+    if img.strides[-1] == img.itemsize:
+        dst[...] = img
+    else:
+        for ch in range(img.shape[-1]):
+            dst[..., ch] = img[..., ch]
+
+
 @dataclass
 class CnnServeConfig:
     max_batch: int = 8          # largest serve bucket (paper's S_batch knob)
@@ -770,7 +784,7 @@ class CnnEngine:
                 h, w, c = reqs[0].image.shape
                 buf = np.zeros((bucket, h, w, c), self._buf_dtype)
                 for i, r in enumerate(reqs):
-                    buf[i] = r.image
+                    _copy_image(buf[i], r.image)
                 if (self.faults is not None
                         and self.faults.fire("stage.corrupt")):
                     # corrupt only the staged copy — req.image stays

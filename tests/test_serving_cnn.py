@@ -17,8 +17,9 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import alexnet
-from repro.serving import (CnnEngine, CnnServeConfig, ImageRequest,
-                           SlotScheduler, bucket_sizes)
+from repro.serving import (CnnEngine, CnnServeConfig, FaultInjector,
+                           FaultSpec, ImageRequest, SlotScheduler,
+                           bucket_sizes)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -208,6 +209,119 @@ def test_data_parallel_bitmatch_subprocess(served):
                        text=True, timeout=600, env=env)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "OK" in r.stdout
+
+
+def _staging_case(bucket: int, data_parallel: bool):
+    """Serve one full bucket and check what crossed to the device: the
+    NHWC batch (split into whole images under data parallelism), logits
+    bit-equal to a jitted ``apply`` on each device's slice, and
+    ``stage.corrupt`` NaNs in row 0 of the staged copy alone."""
+    cfg = get_config("alexnet").reduced()
+    params = alexnet.init(jax.random.PRNGKey(0), cfg)
+    fwd = jax.jit(lambda p, x: alexnet.apply(p, cfg, x))
+    ref = lambda x: fwd(params, x)                          # noqa: E731
+    eng = CnnEngine(cfg, CnnServeConfig(max_batch=4,
+                                        data_parallel=data_parallel),
+                    params=params)
+    hw, c = cfg.image_size, cfg.in_channels
+    imgs = _images(cfg, bucket, seed=bucket)
+    k = bucket // eng._shards(bucket)           # images on each device
+    expect = np.concatenate([np.asarray(ref(imgs[i:i + k]))
+                             for i in range(0, bucket, k)])
+
+    def staged(reqs):
+        for r in reqs:
+            eng.submit(r)
+        eng._stage()
+        images = eng._staged[-1].images
+        assert images.shape == (bucket, hw, hw, c)
+        assert {s.data.shape for s in images.addressable_shards} == {
+            (k, hw, hw, c)}
+        return np.asarray(images)
+
+    reqs = [ImageRequest(image=im) for im in imgs]
+    assert np.array_equal(staged(reqs), imgs)
+    eng.run_until_done()
+    assert np.array_equal(np.stack([r.logits for r in reqs]), expect)
+
+    eng.arm_faults(FaultInjector(0, {"stage.corrupt": FaultSpec(at=(0,))}))
+    reqs = [ImageRequest(image=im) for im in imgs]
+    host = staged(reqs)
+    assert np.isnan(host[0]).all()
+    assert np.array_equal(host[1:], imgs[1:])
+    eng.run_until_done()
+    assert all(r.done for r in reqs)
+    assert [r.attempts for r in reqs] == [1] + [0] * (bucket - 1)
+    # rows 1.. retire from the corrupted batch; row 0 re-staged alone
+    assert all(np.array_equal(r.logits, e)
+               for r, e in zip(reqs[1:], expect[1:]))
+    assert np.array_equal(reqs[0].logits, np.asarray(ref(imgs[:1]))[0])
+
+
+@pytest.mark.parametrize("data_parallel", [False, True],
+                         ids=["one_device", "data_parallel"])
+@pytest.mark.parametrize("bucket", [1, 2, 4])
+def test_staged_batch_serves_bit_equal(bucket, data_parallel):
+    """Under data parallelism on two forced host devices (bucket 1
+    replicated, 2 and 4 split), in a subprocess like the test above."""
+    if not data_parallel:
+        _staging_case(bucket, False)
+        return
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    code = textwrap.dedent(f"""
+        import sys
+        sys.path.insert(0, {os.path.join(ROOT, "tests")!r})
+        import jax
+        assert jax.device_count() == 2
+        from test_serving_cnn import _staging_case
+        _staging_case({bucket}, True)
+        print("OK")
+    """)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    assert "OK" in r.stdout
+
+
+def _as_layout(img, layout):
+    """The same image values held in another memory order or dtype."""
+    if layout == "hwc":
+        return np.ascontiguousarray(img)
+    if layout == "chw":                 # a planar image, transposed
+        return np.ascontiguousarray(img.transpose(2, 0, 1)).transpose(1, 2, 0)
+    if layout == "pool":                # one image of an (H, C, N, W) pool
+        pool = np.stack([img, img[::-1]]).transpose(1, 3, 0, 2).copy()
+        return pool.transpose(2, 0, 3, 1)[0]
+    return img.astype(np.float64)
+
+
+@pytest.fixture(scope="module")
+def bucket1(served):
+    cfg, params, _ = served
+    return CnnEngine(cfg, CnnServeConfig(max_batch=1), params=params)
+
+
+@pytest.mark.parametrize("layout", ["hwc", "chw", "pool", "float64"])
+def test_image_memory_order_does_not_change_what_is_staged(served, bucket1,
+                                                           layout):
+    """An image whose channels are planes is copied a plane at a time;
+    what crosses and what is served are those of its C-ordered float32
+    copy, bit for bit."""
+    cfg, _, ref = served
+    img = _images(cfg, 1, seed=21)[0]
+    src = _as_layout(img, layout)
+    assert np.array_equal(src, img)
+    assert src.flags.c_contiguous == (layout in ("hwc", "float64"))
+    eng = bucket1
+    req = ImageRequest(image=src)
+    eng.submit(req)
+    eng._stage()
+    staged = np.asarray(eng._staged[-1].images)
+    assert np.array_equal(staged, img.reshape(staged.shape))
+    eng.run_until_done()
+    assert np.array_equal(req.logits, np.asarray(ref(img[None]))[0])
 
 
 def test_lowering_error_raises_out_of_engine(served, monkeypatch):
