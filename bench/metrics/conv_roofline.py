@@ -1,7 +1,7 @@
 """The conv kernels' share of their roofline, in %: the least time of the
-conv layers the window launched (each batch at its bucket's rows, by
-``work.py`` and the chip's peaks) / the device time of the Pallas kernel
-ops in the trace."""
+conv layers the window launched (each batch at its bucket's rows, by the
+net's ``work.py`` and the chip's peaks) / the device time of the Pallas
+kernel ops in the trace."""
 
 
 def read(m):

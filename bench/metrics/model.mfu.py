@@ -1,7 +1,7 @@
 """The whole forward's share of the chip's bf16 peak, in %: images served
-in the window x the least operations per image (``work.py``) / (window x
-peak).  Float32 at the highest precision takes several bf16 passes, so it
-reads well under the peak."""
+in the window x the least operations per image (the net's ``work.py``) /
+(window x peak).  Float32 at the highest precision takes several bf16
+passes, so it reads well under the peak."""
 
 
 def read(m):

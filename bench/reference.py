@@ -1,8 +1,10 @@
-"""The plain reference: a configuration's forward in straightforward jnp.
+"""What every net's plain reference shares: the seeded weights and images,
+the precisions, and the reference run in blocks.
 
-It reads the configuration file's layer table and imports nothing of the
-program under test.  Its weights are the harness's own (``make_params``,
-made on the device from the seed in one jitted call); the program is given
+A net's own reference (``bench/nets/<net>/reference.py``) writes its
+parameter shapes and its forward pass in straightforward jnp at float32,
+and imports nothing of the program.  Its weights are the harness's own,
+made on the device from the seed in one jitted call; the program is given
 the same arrays.
 
 * ``forward(cfg, params, x, "highest")`` is the reference: float32 at the
@@ -16,6 +18,9 @@ the same arrays.
   ``reduce_precision``, which XLA keeps: a float32 -> bfloat16 -> float32
   round trip may be dropped on the TPU as excess precision, which made an
   earlier form of this control a one-pass bfloat16 product there.
+
+A net's forward computes each of its products through :func:`product`,
+which is where the precision is applied.
 """
 from __future__ import annotations
 
@@ -38,39 +43,11 @@ def seed_key(seed: int):
                               (seed >> 31) & 0xFFFFFFFF)
 
 
-def param_shapes(cfg: dict) -> dict:
-    """``{layer: {"w": shape, "b": shape}}``: conv weights HWIO (input
-    channels per group), dense weights (in, out)."""
-    shapes, h, c, flat = {}, cfg["image_size"], cfg["in_channels"], None
-    for layer in cfg["layers"]:
-        if layer["op"] == "conv":
-            k, g = layer["kernel"], layer["groups"]
-            shapes[layer["name"]] = {"w": (k, k, c // g, layer["out"]),
-                                     "b": (layer["out"],)}
-            h = _conv_hw(h, layer)
-            c = layer["out"]
-        else:
-            d_in = flat if flat is not None else h * h * c
-            shapes[layer["name"]] = {"w": (d_in, layer["out"]),
-                                     "b": (layer["out"],)}
-            flat = layer["out"]
-    return shapes
-
-
-def _conv_hw(h: int, layer: dict) -> int:
-    k, s = layer["kernel"], layer["stride"]
-    h = -(-h // s) if layer["padding"] == "SAME" else (h - k) // s + 1
-    if layer["pool"]:
-        w, ps = layer["pool"]
-        h = (h - w) // ps + 1
-    return h
-
-
-def make_params(cfg: dict, seed: int) -> dict:
-    """He-normal weights and N(0, 0.1) biases, made on the default device
-    in one jitted call, in the configuration's dtype."""
-    shapes = param_shapes(cfg)
-    dtype = jnp.dtype(cfg["dtype"])
+def he_normal_params(shapes: dict, dtype: str, seed: int) -> dict:
+    """He-normal weights and N(0, 0.1) biases for ``{layer: {"w": shape,
+    "b": shape}}`` (fan-in: every axis of ``w`` but the last), made on the
+    default device in one jitted call, in ``dtype``."""
+    dtype = jnp.dtype(dtype)
 
     def init(key):
         out = {}
@@ -97,7 +74,7 @@ def make_images(cfg: dict, seed: int, n: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# the forward
+# precisions
 # ---------------------------------------------------------------------------
 def _bf16(x):
     """``x`` rounded to bfloat16's 8 mantissa bits, kept in float32."""
@@ -117,71 +94,21 @@ def _three_pass(op, a, b):
     return op(ah, bh) + op(ah, bl) + op(al, bh)
 
 
-def _conv(x, w, layer, precision):
-    def op(a, b, p):
-        return jax.lax.conv_general_dilated(
-            a, b, (layer["stride"],) * 2, layer["padding"],
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=layer["groups"], precision=p,
-            preferred_element_type=jnp.float32)
-    return _apply(op, x, w, precision)
-
-
-def _dense(x, w, precision):
-    def op(a, b, p):
-        return jnp.dot(a, b, precision=p, preferred_element_type=jnp.float32)
-    return _apply(op, x, w, precision)
-
-
-def _apply(op, a, b, precision):
+def product(op, a, b, precision: str):
+    """``op(a, b, lax_precision)``, a bilinear product such as a conv or a
+    dot, at ``precision``: a name in ``PRECISIONS`` or ``"bf16_3x"``."""
     if precision == "bf16_3x":
         return _three_pass(lambda x, y: op(x, y, PRECISIONS["highest"]), a, b)
     return op(a, b, PRECISIONS[precision])
 
 
-def _lrn(x, p):
-    """y[c] = x[c] / (k + alpha/n * sum_{|d| <= n//2} x[c+d]^2)^beta,
-    channels past either end counted as zero."""
-    half = p["n"] // 2
-    sq = jnp.pad(jnp.square(x), ((0, 0),) * 3 + ((half, half),))
-    c = x.shape[-1]
-    win = sum(sq[..., d:d + c] for d in range(p["n"]))
-    return x / jnp.power(p["k"] + p["alpha"] / p["n"] * win, p["beta"])
-
-
-def _maxpool(x, window, stride):
-    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                 (1, window, window, 1),
-                                 (1, stride, stride, 1), "VALID")
-
-
-def forward(cfg: dict, params: dict, x, precision: str = "highest"):
-    """Logits (B, classes) in float32 for images ``x`` (B, H, W, C)."""
-    assert precision in PRECISIONS or precision == "bf16_3x", precision
-    x = x.astype(jnp.float32)
-    for layer in cfg["layers"]:
-        p = params[layer["name"]]
-        if layer["op"] == "conv":
-            x = _conv(x, p["w"], layer, precision) + p["b"].astype(jnp.float32)
-            if layer["relu"]:
-                x = jnp.maximum(x, 0.0)
-            if layer["lrn"]:
-                x = _lrn(x, cfg["lrn"])
-            if layer["pool"]:
-                x = _maxpool(x, *layer["pool"])
-        else:
-            if x.ndim > 2:
-                x = x.reshape(x.shape[0], -1)
-            x = _dense(x, p["w"], precision) + p["b"].astype(jnp.float32)
-            if layer["relu"]:
-                x = jnp.maximum(x, 0.0)
-    return x
-
-
-def logits(cfg: dict, params: dict, images: np.ndarray, precision: str,
-           block: int) -> np.ndarray:
-    """``forward`` over ``images`` in blocks of ``block`` rows (the last
-    block padded), on the host."""
+# ---------------------------------------------------------------------------
+# the reference over a pool of images
+# ---------------------------------------------------------------------------
+def logits(forward, cfg: dict, params: dict, images: np.ndarray,
+           precision: str, block: int) -> np.ndarray:
+    """A net's ``forward`` over ``images`` in blocks of ``block`` rows (the
+    last block padded), on the host."""
     fn = jax.jit(lambda p, x: forward(cfg, p, x, precision))
     out = []
     for i in range(0, len(images), block):

@@ -5,18 +5,22 @@
 
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``,
-its traffic in ``bench/traffic/<traffic>.json`` (driven by
-``bench/traffic/<kind>.py``), and each per-layer metric's reader in
-``bench/metrics/<metric>.py``.
+the network code its configuration names under ``"net"`` in
+``bench/nets/<net>/`` (``reference.py``: ``make_params`` and ``forward``;
+``work.py``: ``layer_work``; ``program.py``: ``program_config``,
+``program_params`` and ``routes``), its traffic in
+``bench/traffic/<traffic>.json`` (driven by ``bench/traffic/<kind>.py``),
+and each per-layer metric's reader in ``bench/metrics/<metric>.py``.
+Only ``serve.py`` and the nets' ``program.py`` import the program.
 
 One run: find the chips (exit 2 where JAX finds no TPU or too few), make
 the weights and images from the seed on the device, build ``CnnEngine``
 and warm every bucket (all of that is ``setup_s``), drive the traffic for
 ``--seconds``, finish what is in flight, read the device's peak memory,
 free the engine, and check a seeded sample of the served logits against
-the plain reference (``reference.py``).  With ``--trace 1`` the window is
-traced and the per-layer metrics are reported instead of the end-to-end
-ones.  The last line of standard output is the result's JSON object; the
+the net's plain reference.  With ``--trace 1`` the window is traced and
+the per-layer metrics are reported instead of the end-to-end ones.  The
+last line of standard output is the result's JSON object; the
 numbers compared, each beside its limit, are also the last lines of
 standard error.
 """
@@ -88,11 +92,30 @@ def metric_reader(name: str):
                         f"bench_metric_{name.replace('.', '_')}")
 
 
+@dataclass(frozen=True)
+class Net:
+    """The network code a configuration names: its modules in
+    ``bench/nets/<name>/``."""
+    name: str
+    reference: object       # make_params(cfg, seed), forward(cfg, ...)
+    work: object            # layer_work(cfg)
+    program: object         # program_config, program_params, routes
+
+
+def net(name: str) -> Net:
+    """The net ``name``; a missing one is a ``FileNotFoundError``."""
+    return Net(name, *(_load_module(
+        os.path.join(HERE, "nets", name, f"{part}.py"),
+        f"bench_net_{name}_{part}") for part in ("reference", "work",
+                                                 "program")))
+
+
 @dataclass
 class Cell:
     name: str
     chips: int
     config: dict            # the configuration file
+    net: Net                # the net the configuration names
     traffic: dict           # the traffic file
     end_to_end: list        # BENCHMARK.json metric entries of this cell
     per_layer: list
@@ -108,7 +131,8 @@ def cell(name: str, root: str = ROOT) -> Cell:
     def mine(metrics):
         return [m for m in metrics if name in m.get("workloads", [name])]
 
-    return Cell(name, w["chips"], config_file(w["config"], root),
+    cfg = config_file(w["config"], root)
+    return Cell(name, w["chips"], cfg, net(cfg["net"]),
                 traffic_file(w["traffic"]), mine(bm["end_to_end"]),
                 mine(bm["per_layer"]))
 
@@ -154,7 +178,7 @@ class Measured:
     batches: dict           # bucket -> batches retired in the window
     served_in_window: int
     trace: object           # trace.Reduction or None
-    layers: list            # work.LayerWork per layer, one image
+    layers: list            # the net's work.LayerWork per layer, one image
     image_flops: int
     peaks: dict
 
@@ -162,10 +186,10 @@ class Measured:
 # ---------------------------------------------------------------------------
 # the run
 # ---------------------------------------------------------------------------
-def logit_err(cfg: dict, params, pool, sample: list, precision: str,
+def logit_err(c: Cell, params, pool, sample: list, precision: str,
               block: int) -> float:
     """Widest gap, over the sampled requests, between a served row and the
-    reference's row for its image at ``precision``, relative to the
+    net's reference row for its image at ``precision``, relative to the
     reference row's largest magnitude."""
     import reference
 
@@ -173,7 +197,8 @@ def logit_err(cfg: dict, params, pool, sample: list, precision: str,
         return float("inf")
     images = sorted({img for img, _, _ in sample})
     where = {img: i for i, img in enumerate(images)}
-    ref = reference.logits(cfg, params, pool[images], precision, block)
+    ref = reference.logits(c.net.reference.forward, c.config, params,
+                           pool[images], precision, block)
     return max(float(np.abs(got - ref[where[img]]).max()
                      / np.abs(ref[where[img]]).max())
                for img, got, _ in sample)
@@ -213,20 +238,20 @@ def setup(c: Cell, seed: int, *, require_chip: bool = True,
     marks = {}
 
     t = time.perf_counter()
-    params = reference.make_params(cfg, seed)
+    params = c.net.reference.make_params(cfg, seed)
     jax.block_until_ready(params)
     marks["params_s"] = time.perf_counter() - t
     t = time.perf_counter()
     images = reference.make_images(cfg, seed, traffic["pool"])
     marks["images_s"] = time.perf_counter() - t
     t = time.perf_counter()
-    server = serve.EngineServer(cfg, params)
+    server = serve.EngineServer(cfg, params, c.net.program)
     if server_hook is not None:
         server_hook(server)
     compile_s = server.warm(traffic.get("buckets"))
     marks["engine_s"] = time.perf_counter() - t
     kernel_names = server.kernel_names()
-    print("conv routes: " + " ".join(f"{n}={r}" for n, r in server.routes),
+    print("routes: " + " ".join(f"{n}={r}" for n, r in server.routes),
           file=sys.stderr)
     print("bucket seconds (compile or cache load): " + " ".join(
         f"{b}={s:.2f}" for b, s in compile_s.items()), file=sys.stderr)
@@ -247,8 +272,8 @@ def run_cell(c: Cell, seed: int, seconds: float, trace: bool, *,
              require_chip: bool = True, server_hook=None,
              controls=()) -> dict:
     """One run of cell ``c``; returns the result object, with the reading
-    of each precision in ``controls`` (``reference.forward``'s names) put
-    in the served logits' place under ``"controls"``."""
+    of each precision in ``controls`` (``reference.PRECISIONS`` or
+    ``"bf16_3x"``) put in the served logits' place under ``"controls"``."""
     import jax
 
     import device
@@ -298,13 +323,13 @@ def run_cell(c: Cell, seed: int, seconds: float, trace: bool, *,
     served_in = sum(r.served and r.t_done <= out.t_close for r in recs)
     t = time.perf_counter()
     block = cfg["check"]["block"]
-    err = logit_err(cfg, params, images, sampler.items, "highest", block)
-    control_errs = {p: logit_err(cfg, params, images, sampler.items, p,
+    err = logit_err(c, params, images, sampler.items, "highest", block)
+    control_errs = {p: logit_err(c, params, images, sampler.items, p,
                                  block) for p in controls}
     ref_s = time.perf_counter() - t
 
-    layers = work.layer_work(cfg)
-    flops = work.image_flops(cfg)
+    layers = c.net.work.layer_work(cfg)
+    flops = work.image_flops(layers)
     direct = 2 * sum(w.direct_macs for w in layers)
     rows = sum(b * n for b, n in batches.items())
     print(f"window {out.seconds:.3f}s: {attempted} sent, {served_in} served "
@@ -386,8 +411,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    c = cell(args.workload)
     os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
+    c = cell(args.workload)
     import device
 
     try:

@@ -1,14 +1,15 @@
 """The system under test, as the harness drives it: ``CnnEngine``.
 
-This is the one module of the benchmark that imports the program.  It
-builds the engine for a configuration file, with the harness's weights,
+With each net's ``bench/nets/<net>/program.py``, the only code of the
+benchmark that imports the program, and it puts the program's ``src`` on
+the path for them.  It builds the engine for a configuration file, with
+the harness's weights in the tree that the net's ``program.py`` gives,
 warms every bucket the cell uses, and exposes submit / step / idle and the
 counters the per-layer metrics read.  It takes from the program only the
 serving entry, its counters and its kernel names.
 """
 from __future__ import annotations
 
-import dataclasses
 import os
 import re
 import sys
@@ -20,69 +21,23 @@ _CUSTOM_CALL = re.compile(
     r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"")
 
 
-class ConfigMismatch(RuntimeError):
-    """The program would run another network than the configuration file
-    states."""
-
-
-def program_config(cfg: dict):
-    """The program's model config for a configuration file: its registered
-    config with the file's sizes, on the file's route.  Fails unless the
-    program's layer geometry is the file's layer table."""
-    from repro.configs import get_config
-    from repro.launch.serve import apply_cnn_route
-    from repro.models.alexnet import layer_specs
-
-    convs = [l for l in cfg["layers"] if l["op"] == "conv"]
-    fcs = [l for l in cfg["layers"] if l["op"] == "fc"]
-    base = get_config(cfg["model"])
-    kw = dict(image_size=cfg["image_size"], in_channels=cfg["in_channels"],
-              conv_channels=tuple(l["out"] for l in convs),
-              fc_dims=tuple(l["out"] for l in fcs),
-              num_classes=fcs[-1]["out"], dtype=cfg["dtype"])
-    if base.arch == "vgg":
-        kw["pool_after"] = tuple(i + 1 for i, l in enumerate(convs)
-                                 if l["pool"])
-    pcfg = apply_cnn_route(dataclasses.replace(base, **kw), cfg["route"])
-    if cfg["lrn"]:
-        lrn = cfg["lrn"]
-        if (pcfg.lrn_n, pcfg.lrn_k, pcfg.lrn_alpha, pcfg.lrn_beta) != (
-                lrn["n"], lrn["k"], lrn["alpha"], lrn["beta"]):
-            raise ConfigMismatch(f"LRN constants differ: {pcfg}")
-    for layer, spec in zip(convs, layer_specs(pcfg)):
-        want = (layer["kernel"], layer["stride"], layer["padding"],
-                layer["groups"], layer["relu"], layer["lrn"],
-                tuple(layer["pool"]) if layer["pool"] else None)
-        got = (spec.kernel, spec.stride, spec.padding, spec.groups,
-               spec.relu, spec.fuse_lrn,
-               (spec.pool_window, spec.pool_stride) if spec.fuse_pool
-               else None)
-        if want != got:
-            raise ConfigMismatch(f"{layer['name']}: file {want}, "
-                                 f"program {got}")
-    return pcfg
-
-
-def program_params(cfg: dict, params: dict) -> dict:
-    """The harness's weights in the program's tree: the same arrays."""
-    return {l["name"]: {"w": params[l["name"]]["w"],
-                        "b": params[l["name"]]["b"]} for l in cfg["layers"]}
-
-
 class EngineServer:
-    """``CnnEngine`` over one configuration file."""
+    """``CnnEngine`` over one configuration file, built through its net's
+    ``program`` module (``program_config``, ``program_params``,
+    ``routes``)."""
 
-    def __init__(self, cfg: dict, params: dict):
-        from repro.models.alexnet import layer_routes
+    def __init__(self, cfg: dict, params: dict, program):
         from repro.serving import CnnEngine, CnnServeConfig, ImageRequest
 
         self._request = ImageRequest
-        self.pcfg = program_config(cfg)
-        self.routes = layer_routes(self.pcfg)
+        self._image = ((cfg["image_size"], cfg["image_size"],
+                        cfg["in_channels"]), cfg["dtype"])
+        pcfg = program.program_config(cfg)
+        self.routes = program.routes(pcfg)
         self.engine = CnnEngine(
-            self.pcfg, CnnServeConfig(max_batch=cfg["max_batch"],
-                                      staging_depth=cfg["staging_depth"]),
-            params=program_params(cfg, params))
+            pcfg, CnnServeConfig(max_batch=cfg["max_batch"],
+                                 staging_depth=cfg["staging_depth"]),
+            params=program.program_params(cfg, params))
 
     def warm(self, buckets=None) -> dict:
         """Serve one full group in each of ``buckets`` (default: the whole
@@ -91,8 +46,8 @@ class EngineServer:
         import numpy as np
 
         eng = self.engine
-        hw, c = self.pcfg.image_size, self.pcfg.in_channels
-        img = np.zeros((hw, hw, c), np.dtype(self.pcfg.dtype))
+        shape, dtype = self._image
+        img = np.zeros(shape, np.dtype(dtype))
         for b in buckets or eng.buckets:
             for _ in range(b):
                 eng.submit(self._request(image=img))

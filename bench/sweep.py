@@ -35,7 +35,8 @@ def main(argv=None) -> int:
     import driver
 
     base = {"kind": args.kind, "pool": args.pool}
-    c = run.Cell("sweep", 1, run.config_file(args.config), base, [], [])
+    cfg = run.config_file(args.config)
+    c = run.Cell("sweep", 1, cfg, run.net(cfg["net"]), base, [], [])
     s = run.setup(c, args.seed)
     kind = run.traffic_kind(args.kind)
     for rate in (float(r) for r in args.rates.split(",")):

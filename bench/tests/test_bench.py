@@ -2,9 +2,10 @@
 
     JAX_PLATFORMS=cpu python -m pytest -q bench/tests
 
-The yardstick (``work.py``, ``trace.py``, the traffic generators), the
-discovery of cells, configurations, traffic and metrics by name, the
-refusal to run without a chip, and whole runs at a CPU-test size
+The yardstick (``work.py`` and each net's, ``trace.py``, the traffic
+generators), the discovery of cells, configurations, nets, traffic and
+metrics by name, what the net ``chain`` reads (frozen), the refusal to
+run without a chip, and whole runs at a CPU-test size
 (``data/<config>_tiny.json``, Pallas kernels in interpret mode) that skip
 only the look for a chip: the three-pass bfloat16 control must fail the
 full-size configuration's ``logit_err`` limit that the program passes,
@@ -34,15 +35,23 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
+CONFIGS = [c["name"] for c in run.benchmark()["configs"]]
+
+
 def _cfg(name):
     return run.config_file(name)
+
+
+def _layer_work(name):
+    cfg = _cfg(name)
+    return run.net(cfg["net"]).work.layer_work(cfg)
 
 
 # ---------------------------------------------------------------------------
 # work.py
 # ---------------------------------------------------------------------------
 def test_alexnet_layer_counts():
-    layers = {w.name: w for w in work.layer_work(_cfg("alexnet"))}
+    layers = {w.name: w for w in _layer_work("alexnet")}
     assert layers["conv3"].direct_macs == 149_520_384
     assert layers["conv3"].least_macs == 56_623_104      # F(4,3), 4x4 tiles
     assert layers["conv1"].least_macs == 105_415_200     # 11x11 s4: direct
@@ -50,7 +59,7 @@ def test_alexnet_layer_counts():
     conv = sum(w.least_macs for w in layers.values() if w.op == "conv")
     fc = sum(w.least_macs for w in layers.values() if w.op == "fc")
     assert (conv, fc) == (456_765_984, 58_621_952)
-    assert work.image_flops(_cfg("alexnet")) == 2 * (conv + fc)
+    assert work.image_flops(list(layers.values())) == 2 * (conv + fc)
     fc_w = sum(w.weight_bytes for w in layers.values() if w.op == "fc")
     assert fc_w == 234_524_576                            # float32
     # conv5 writes its pooled 6x6x256 map; fc6 reads 9216 features
@@ -59,7 +68,7 @@ def test_alexnet_layer_counts():
 
 
 def test_vgg16_layer_counts():
-    layers = work.layer_work(_cfg("vgg16"))
+    layers = _layer_work("vgg16")
     assert len(layers) == 16
     conv = sum(w.least_macs for w in layers if w.op == "conv")
     fc = sum(w.least_macs for w in layers if w.op == "fc")
@@ -215,7 +224,9 @@ def test_benchmark_json_shape():
                                   run.benchmark()["workloads"]])
 def test_cell_found_by_name(name):
     c = run.cell(name)
-    assert c.config["model"] in ("alexnet", "vgg16")
+    w = {w["name"]: w for w in run.benchmark()["workloads"]}[name]
+    assert c.config == _cfg(w["config"])
+    assert c.net.name == c.config["net"]
     kind = run.traffic_kind(c.traffic["kind"])
     assert callable(kind.drive)
     for m in c.per_layer:
@@ -231,14 +242,173 @@ def test_unknown_names_fail():
         run.traffic_kind("no_such_kind")
 
 
-def test_configs_match_the_program():
-    """Each configuration file is the network the program runs."""
-    import serve
+@pytest.mark.parametrize("name", CONFIGS)
+def test_each_config_finds_its_net(name):
+    """The net a configuration names, with what the harness calls of each
+    of its modules."""
+    cfg = _cfg(name)
+    net = run.net(cfg["net"])
+    assert net.name == cfg["net"]
+    for mod, fns in ((net.reference, ("make_params", "forward")),
+                     (net.work, ("layer_work",)),
+                     (net.program, ("program_config", "program_params",
+                                    "routes"))):
+        for fn in fns:
+            assert callable(getattr(mod, fn)), (mod.__name__, fn)
+    layers = net.work.layer_work(cfg)
+    assert layers and all(isinstance(w, work.LayerWork) for w in layers)
 
-    for name in ("alexnet", "vgg16"):
-        cfg = _cfg(name)
-        pcfg = serve.program_config(cfg)
-        assert pcfg.use_pallas and pcfg.image_size == cfg["image_size"]
+
+@pytest.mark.parametrize("net,error", [(None, KeyError),
+                                       ("no_such_net", FileNotFoundError)])
+def test_unknown_or_missing_net_fails_before_chip_work(monkeypatch, net,
+                                                       error):
+    """A configuration with no ``"net"``, or naming one that is not in
+    ``bench/nets/``, fails in ``run.main`` before the run starts: no
+    default net is taken."""
+    cfg = dict(_cfg("alexnet"))
+    del cfg["net"]
+    if net is not None:
+        cfg["net"] = net
+    monkeypatch.setattr(run, "config_file", lambda name, root=run.ROOT: cfg)
+
+    def no_run(*a, **kw):
+        raise AssertionError("the run started")
+
+    monkeypatch.setattr(run, "run_cell", no_run)
+    # restored after the test: main sets it
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", run.CACHE_DIR)
+    with pytest.raises(error):
+        run.main(["--workload", "alexnet.closed", "--seed", "1",
+                  "--seconds", "1"])
+    with pytest.raises(FileNotFoundError):
+        run.net("no_such_net")
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_configs_match_the_program(name):
+    """Each configuration file is the network the program runs, as its
+    net's ``program.py`` builds it."""
+    import serve  # noqa: F401  (puts the program on the path)
+
+    cfg = _cfg(name)
+    program = run.net(cfg["net"]).program
+    pcfg = program.program_config(cfg)
+    assert (pcfg.image_size, pcfg.in_channels) == (cfg["image_size"],
+                                                   cfg["in_channels"])
+    routes = program.routes(pcfg)
+    assert routes and all(r.startswith(cfg["route"]) for _, r in routes)
+
+
+# ---------------------------------------------------------------------------
+# the net chain, frozen
+# ---------------------------------------------------------------------------
+# What ``chain`` read at its first commit (the parent of the net lookup),
+# on the CPU, at ``FROZEN_SEED``: per leaf of ``make_params`` the float64
+# sum and sum of squares; of ``make_images(cfg, seed, 3)`` the same; and
+# the reference logits of those images at ``highest`` in blocks of 2.
+FROZEN_SEED = 2**31 + 17
+FROZEN = {
+    "alexnet": {
+        "params": {
+            "conv1.b": (0.0441775768995285, 0.21884347043508154),
+            "conv1.w": (2.4711815375253536, 32.667163076785144),
+            "conv2.b": (0.8482502992264926, 0.3668175367044847),
+            "conv2.w": (1.3158473928087915, 64.32559430568239),
+            "conv3.b": (-0.40333214693237096, 0.5376761114557391),
+            "conv3.w": (9.553352431803432, 95.10822748648398),
+            "conv4.b": (0.41678820294328034, 0.5841689830664871),
+            "conv4.w": (-0.6937892260839931, 96.18780533826865),
+            "conv5.b": (-0.21393360616639256, 0.30155202046067),
+            "conv5.w": (-3.5444238221311934, 63.80833341566193),
+            "fc6.b": (0.04604844911955297, 0.6588141741993117),
+            "fc6.w": (-0.8178086030638951, 121.50512034400552),
+            "fc7.b": (0.5918653649277985, 0.504039420818491),
+            "fc7.w": (5.311228755352204, 96.98701950977971),
+            "fc8.b": (-0.09735004510730505, 0.033363512900506445),
+            "fc8.w": (2.338523880811408, 20.60708492099308),
+        },
+        "images": (184.7122314119697, 39979.391038336944),
+        "logits": [
+            [0.19129794836044312, -0.3379075825214386, 1.027381181716919,
+             -0.5474968552589417, 0.05909112095832825, 1.3328830003738403,
+             0.9268745183944702, -2.324575185775757, -1.397199273109436,
+             -1.8121304512023926],
+            [0.18134421110153198, -0.837714433670044, 1.3748196363449097,
+             -0.23998630046844482, -0.49228277802467346, 1.21260404586792,
+             1.4161933660507202, -2.900394916534424, -1.551550030708313,
+             -1.163405418395996],
+            [0.40885961055755615, -0.6351401805877686, 0.6955592632293701,
+             -0.44416916370391846, -0.07617220282554626, 1.4546242952346802,
+             0.7182179689407349, -2.2957279682159424, -1.1639939546585083,
+             -1.3613545894622803],
+        ],
+    },
+    "vgg16": {
+        "params": {
+            "conv1.b": (0.08829245902597904, 0.03842287707665054),
+            "conv1.w": (9.300951658224221, 17.412278036640487),
+            "conv2.b": (0.1776267522946, 0.24861342690009286),
+            "conv2.w": (-3.547113966429606, 33.83786549684202),
+            "conv3.b": (-0.4352276511490345, 0.20894043083031),
+            "conv3.w": (11.809183386256336, 33.18405571813711),
+            "conv4.b": (-0.6053279673215002, 0.3385602997809874),
+            "conv4.w": (10.764836905733773, 48.01543494783575),
+            "fc6.b": (-0.21393360616639256, 0.30155202046067),
+            "fc6.w": (-6.862208723911408, 62.982802309782954),
+            "fc7.b": (0.7282909008208662, 0.31499806041754835),
+            "fc7.w": (2.5498238860163838, 44.037178033130274),
+            "fc8.b": (0.23785688821226358, 0.10662090327286765),
+            "fc8.w": (-1.5235028200550005, 21.659749080974642),
+        },
+        "images": (100.4764169056325, 8991.309258229883),
+        "logits": [
+            [-4.547572135925293, 2.3755033016204834, 1.2641899585723877,
+             -0.7505550384521484, -3.419731616973877, -0.890582799911499,
+             -0.41442394256591797, 2.0515193939208984, 5.2618608474731445,
+             -2.166632890701294],
+            [-7.728527545928955, 2.379939079284668, 3.024289846420288,
+             -1.9153354167938232, -1.6357078552246094, 2.1956470012664795,
+             -0.5074640512466431, -1.2162795066833496, 4.655242919921875,
+             -1.6501957178115845],
+            [-6.053717613220215, 2.775672197341919, 2.4062771797180176,
+             -3.0160973072052, -1.6143763065338135, 2.58636212348938,
+             -0.1437176614999771, 0.3481449484825134, 4.557588577270508,
+             -1.453607439994812],
+        ],
+    },
+}
+
+
+def _tiny_cfg(model):
+    with open(os.path.join(BENCH, "tests", "data", f"{model}_tiny.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("model", sorted(FROZEN))
+def test_chain_reads_as_frozen(model):
+    """``chain``'s weights, the shared seeded images and ``chain``'s
+    reference logits are what they were before the net was looked up by
+    name: a move of code changes no number the harness reads."""
+    import reference
+
+    cfg = _tiny_cfg(model)
+    want = FROZEN[model]
+    ref = run.net(cfg["net"]).reference
+    params = ref.make_params(cfg, FROZEN_SEED)
+    got = {f"{k}.{w}": (float(np.asarray(v, np.float64).sum()),
+                        float(np.square(np.asarray(v, np.float64)).sum()))
+           for k, d in sorted(params.items()) for w, v in sorted(d.items())}
+    assert list(got) == list(want["params"])
+    for leaf, sums in want["params"].items():
+        assert got[leaf] == pytest.approx(sums, rel=1e-6), leaf
+    x = reference.make_images(cfg, FROZEN_SEED, 3)
+    x64 = x.astype(np.float64)
+    assert (x64.sum(), np.square(x64).sum()) == pytest.approx(
+        want["images"], rel=1e-6)
+    logits = reference.logits(ref.forward, cfg, params, x, "highest", 2)
+    np.testing.assert_allclose(logits, np.array(want["logits"]), rtol=1e-5,
+                               atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -287,14 +457,13 @@ def test_run_without_a_chip_fails_without_a_result():
 def _tiny_cell(model="alexnet"):
     """The closed cell of ``model`` at CPU-test size, held to the limit of
     the full-size configuration."""
-    with open(os.path.join(BENCH, "tests", "data", f"{model}_tiny.json")) as f:
-        cfg = json.load(f)
+    cfg = _tiny_cfg(model)
     cfg["check"]["logit_err"] = _cfg(model)["check"]["logit_err"]
     closed = run.cell(f"{model}.closed")
     traffic = {"kind": "closed", "clients": 2 * cfg["max_batch"], "pool": 8,
                "buckets": [cfg["max_batch"]]}
-    return run.Cell(closed.name, 1, cfg, traffic, closed.end_to_end,
-                    closed.per_layer)
+    return run.Cell(closed.name, 1, cfg, run.net(cfg["net"]), traffic,
+                    closed.end_to_end, closed.per_layer)
 
 
 @pytest.mark.parametrize("model", ["alexnet", "vgg16"])

@@ -1,24 +1,27 @@
-"""Operations and bytes of each layer, from its shapes alone.
+"""Operations and bytes of each layer, from its shapes alone: what every
+net's work count shares.
 
-The yardstick for ``model.mfu`` and ``conv_roofline``.  It reads a
-configuration file's layer table (``bench/configs/<name>.json``) and
-nothing of the program, so it stays the same whichever kernel a later
-change runs a layer on.
+The yardstick for ``model.mfu`` and ``conv_roofline``.  A net's
+``bench/nets/<net>/work.py`` gives ``layer_work(cfg)``, a
+:class:`LayerWork` per layer from the configuration file alone; it reads
+nothing of the program, so the count stays the same whichever kernel a
+later change runs a layer on.
 
 * Operations are 2 x the fewest multiply-adds any route of this datapath
   needs: the Winograd F(4,3) count for 3x3 stride-1 convs (36 products per
-  4x4 output tile and channel pair, edge tiles whole), the direct count for
-  every other conv, and the dense count for FC layers.  No kernel of the
-  program does fewer, so a share of it cannot pass 100% honestly.
+  4x4 output tile and channel pair, edge tiles whole, :func:`conv_macs`),
+  the direct count for every other conv, and the dense count for FC
+  layers.  No kernel of the program does fewer, so a share of it cannot
+  pass 100% honestly.
 * Bytes are the layer's input, output and weights, each moved once, in the
   configuration's dtype.  A conv layer's output is what it writes: after
-  its fused pool.
+  its fused epilogue.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-_DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
+DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
 
 # Winograd F(m, r): each m x m output tile costs (m + r - 1)^2 products
 WINOGRAD_M = 4
@@ -62,11 +65,6 @@ def conv_out(h: int, kernel: int, stride: int, padding: str) -> int:
     return (h - kernel) // stride + 1
 
 
-def pool_out(h: int, window: int, stride: int) -> int:
-    """VALID max-pool extent."""
-    return (h - window) // stride + 1
-
-
 def conv_macs(h_out: int, w_out: int, c_in: int, c_out: int, kernel: int,
               stride: int) -> tuple:
     """(direct, least) multiply-adds of one conv for one image; ``c_in`` is
@@ -79,33 +77,7 @@ def conv_macs(h_out: int, w_out: int, c_in: int, c_out: int, kernel: int,
     return direct, min(direct, tiles * n * n * c_in * c_out)
 
 
-def layer_work(cfg: dict) -> list:
-    """Per-image :class:`LayerWork` of every layer of a configuration."""
-    size = _DTYPE_BYTES[cfg["dtype"]]
-    h, c = cfg["image_size"], cfg["in_channels"]
-    flat = None
-    out = []
-    for layer in cfg["layers"]:
-        if layer["op"] == "conv":
-            k, s, g = layer["kernel"], layer["stride"], layer["groups"]
-            ho = conv_out(h, k, s, layer["padding"])
-            direct, least = conv_macs(ho, ho, c // g, layer["out"], k, s)
-            hp = pool_out(ho, *layer["pool"]) if layer["pool"] else ho
-            out.append(LayerWork(
-                layer["name"], "conv", direct, least,
-                h * h * c * size, hp * hp * layer["out"] * size,
-                (k * k * (c // g) * layer["out"] + layer["out"]) * size))
-            h, c = hp, layer["out"]
-        else:
-            d_in = flat if flat is not None else h * h * c
-            macs = d_in * layer["out"]
-            out.append(LayerWork(
-                layer["name"], "fc", macs, macs, d_in * size,
-                layer["out"] * size, (macs + layer["out"]) * size))
-            flat = layer["out"]
-    return out
-
-
-def image_flops(cfg: dict) -> int:
-    """Least operations of one image's whole forward."""
-    return sum(w.flops for w in layer_work(cfg))
+def image_flops(layers: list) -> int:
+    """Least operations of one image's whole forward, over a net's
+    ``layer_work``."""
+    return sum(w.flops for w in layers)
