@@ -21,10 +21,11 @@ _MODULES = {
     "phi-3-vision-4.2b": "phi3_vision_4p2b",
     "alexnet": "alexnet",
     "vgg16": "vgg16",
+    "resnet50": "resnet50",
 }
 
 # the paper-side CNNs live outside the assigned-architecture list
-CNN_ARCHS = ["alexnet", "vgg16"]
+CNN_ARCHS = ["alexnet", "vgg16", "resnet50"]
 ASSIGNED = [n for n in _MODULES if n not in CNN_ARCHS]
 
 

@@ -53,18 +53,18 @@ no tile-alignment constraint here, since rows are computed directly).
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.winograd import SUBLANES, auto_pool_rows
+from ...core.winograd import LANES, SUBLANES, auto_pool_rows
 from ..compat import tpu_compiler_params
 from . import dma
-from .epilogue import F32_DOT, batch_blocks, channel_blocks, fused_epilogue, \
-    grouped_channel_pad, k_blocks, vmem_limit
+from .epilogue import F32_DOT, VMEM_NEED_MAX, batch_blocks, channel_blocks, \
+    fused_epilogue, grouped_channel_pad, k_blocks, vmem_limit, vmem_need
 
 
 def same_pad(extent: int, r: int, stride: int) -> tuple[int, int, int]:
@@ -72,6 +72,15 @@ def same_pad(extent: int, r: int, stride: int) -> tuple[int, int, int]:
     out = -(-extent // stride)
     total = max((out - 1) * stride + r - extent, 0)
     return out, total // 2, total - total // 2
+
+
+def pads(extent: int, r: int, stride: int, padding) -> tuple[int, int, int]:
+    """(out, pad_lo, pad_hi) for "SAME", "VALID" or explicit ``(lo, hi)``
+    padding of one spatial axis."""
+    if padding == "SAME":
+        return same_pad(extent, r, stride)
+    lo, hi = (0, 0) if padding == "VALID" else padding
+    return (extent + lo + hi - r) // stride + 1, lo, hi
 
 
 @dataclass(frozen=True)
@@ -113,6 +122,7 @@ class DirectPlan:
     Kb: int
     nkb: int
     checksum: bool = False  # ABFT checksum row on every weight tile
+    residual: bool = False  # a (Bb, Rc, wc, Kb) residual tile per K block
 
     @property
     def Kfull(self) -> int:
@@ -125,8 +135,8 @@ class DirectPlan:
                               spatial=(self.r, self.r),
                               checksum=self.checksum)
 
-    @property
-    def vmem_limit_bytes(self) -> int:
+    def _buffers(self) -> tuple[list, list]:
+        """(pipelined, scratch) VMEM block shapes of one launch."""
         tile = (1, *self.weights.tile_shape)
         pipelined = [(self.Bb, self.Hp, self.Wp, self.Cb),
                      (self.g * self.nkb, self.Kb),
@@ -137,15 +147,32 @@ class DirectPlan:
             pipelined.append(tile)
         else:
             scratch.append((2, *tile[1:]))
-        return vmem_limit(pipelined, scratch)
+        if self.residual:
+            pipelined.append((self.Bb, self.Rc, self.wc, self.Kb))
+        return pipelined, scratch
+
+    @property
+    def vmem_need_bytes(self) -> int:
+        return vmem_need(*self._buffers())
+
+    @property
+    def vmem_limit_bytes(self) -> int:
+        return vmem_limit(*self._buffers())
 
 
-def plan(x_shape, w_shape, *, stride: int = 1, padding: str = "SAME",
+def plan(x_shape, w_shape, *, stride: int = 1, padding="SAME",
          pool=None, groups: int = 1, row_block: int = 8,
          pool_row_block: int | None = None, c_block: int | None = None,
          k_block: int = 128, batch_block: int = 8,
-         checksum: bool = False) -> DirectPlan:
-    """Derive the full launch plan from shapes + static params."""
+         checksum: bool = False, residual: bool = False) -> DirectPlan:
+    """Derive the full launch plan from shapes + static params.
+
+    ``padding`` is "SAME", "VALID" or explicit ``(lo, hi)`` pads on each
+    spatial side.  The batch block is ``batch_block`` unless the launch's
+    buffers would not fit VMEM with it: a plane of at most 128 channels
+    cannot split into channel blocks (``auto_c_block``), so it is halved
+    until they fit (ResNet's 224 px stem at 8 images).
+    """
     r_in, s, g = w_shape[0], stride, groups
     assert w_shape[0] == w_shape[1], "square filters only"
     B, H, W, Ct = x_shape
@@ -153,12 +180,8 @@ def plan(x_shape, w_shape, *, stride: int = 1, padding: str = "SAME",
     assert Ct % g == 0 and Kt % g == 0 and w_shape[2] == Ct // g, (
         "grouped conv shape mismatch")
     K = Kt // g
-    if padding == "SAME":
-        out_h, ph_lo, _ = same_pad(H, r_in, s)
-        out_w, pw_lo, _ = same_pad(W, r_in, s)
-    else:
-        ph_lo = pw_lo = 0
-        out_h, out_w = (H - r_in) // s + 1, (W - r_in) // s + 1
+    out_h, ph_lo, _ = pads(H, r_in, s, padding)
+    out_w, pw_lo, _ = pads(W, r_in, s, padding)
     assert out_h >= 1 and out_w >= 1, (H, W, r_in, s, padding)
     r, C = -(-r_in // s), s * s * (Ct // g)     # space-to-depth geometry
 
@@ -195,15 +218,25 @@ def plan(x_shape, w_shape, *, stride: int = 1, padding: str = "SAME",
     Wp = wc - 1 + r
 
     Cb = channel_blocks(C, c_block, Hp, Wp, Bb, groups=g)
-    Cp = C + (-C) % Cb
     Kb = k_blocks(K, k_block)
-    return DirectPlan(r=r, s=s, r_in=r_in, g=g, C=C, K=K, out_h=out_h,
-                      out_w=out_w, wc=wc, ph_lo=ph_lo, pw_lo=pw_lo,
-                      ph_out=ph_out, pw_out=pw_out, Rc=Rc, step_in=step_in,
-                      in_rows=in_rows, npr=npr, rows_out=rows_out,
-                      w_out=w_out, Hp=Hp, Wp=Wp, Bb=Bb, Bp=Bp, Cb=Cb,
-                      Cp=Cp, ncb=Cp // Cb, Kb=Kb, nkb=K // Kb,
-                      checksum=checksum)
+    if c_block is None and g == 1 and Kb % LANES and Cb < C:
+        # a K block narrower than the lanes cannot be a slot of the 2-slot
+        # DMA buffer (Mosaic slices it whole-tile only), so every channel
+        # stays resident and the filters are one tile (ResNet's 256 -> 64
+        # 1x1 convs)
+        Cb = C
+    Cp = C + (-C) % Cb
+    p = DirectPlan(r=r, s=s, r_in=r_in, g=g, C=C, K=K, out_h=out_h,
+                   out_w=out_w, wc=wc, ph_lo=ph_lo, pw_lo=pw_lo,
+                   ph_out=ph_out, pw_out=pw_out, Rc=Rc, step_in=step_in,
+                   in_rows=in_rows, npr=npr, rows_out=rows_out,
+                   w_out=w_out, Hp=Hp, Wp=Wp, Bb=Bb, Bp=Bp, Cb=Cb,
+                   Cp=Cp, ncb=Cp // Cb, Kb=Kb, nkb=K // Kb,
+                   checksum=checksum, residual=residual)
+    while p.Bb > 1 and p.vmem_need_bytes > VMEM_NEED_MAX:
+        Bb, Bp = batch_blocks(B, -(-p.Bb // 2))
+        p = replace(p, Bb=Bb, Bp=Bp)
+    return p
 
 
 def _filters_to_depth(w, p: DirectPlan):
@@ -243,9 +276,13 @@ def pack_weights(w, p: DirectPlan):
     return dma.pack_weight_tiles(wg, p.weights)
 
 
-def _direct_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, relu: bool,
-                   checksum: bool, lrn, pool, step_in: int, prefetch: bool,
-                   single: bool, row_parallel: bool):
+def _direct_kernel(x_ref, w_tiles, b_ref, *refs, residual: bool,
+                   relu: bool, checksum: bool, lrn, pool, step_in: int,
+                   prefetch: bool, single: bool, row_parallel: bool):
+    res_ref = None
+    if residual:
+        res_ref, *refs = refs
+    out_ref, *refs = refs
     if checksum:
         sdc_ref, acc_ref, y_ref, wbuf, sem = refs
     else:
@@ -285,6 +322,8 @@ def _direct_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, relu: bool,
     @pl.when(c == nc - 1)
     def _store_kblock():
         y = acc_ref[bi] + b_ref[pl.ds(k, 1), :].astype(jnp.float32)
+        if res_ref is not None:                     # conv + b + shortcut
+            y = y + res_ref[bi].astype(jnp.float32)
         if relu:
             y = jnp.maximum(y, 0.0)
         y_ref[bi, k] = y
@@ -301,8 +340,9 @@ def _direct_kernel(x_ref, w_tiles, b_ref, out_ref, *refs, relu: bool,
                                              "batch_block", "weight_prefetch",
                                              "row_parallel", "checksum",
                                              "interpret", "name"))
-def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
-                  padding: str = "SAME", relu: bool = False, groups: int = 1,
+def conv2d_direct(x, w, b=None, w_packed=None, residual=None, *,
+                  stride: int = 1, padding="SAME", relu: bool = False,
+                  groups: int = 1,
                   lrn=None, pool=None, row_block: int = 8,
                   pool_row_block: int | None = None,
                   c_block: int | None = None, k_block: int = 128,
@@ -345,12 +385,23 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
 
     ``name`` names the ``pallas_call``, and so the kernel's HLO instruction
     and its device op in a profile (``conv1_direct``).
+
+    ``residual`` (B, out_h, out_w, K), a residual block's shortcut, is
+    added in the epilogue after the bias and before the ReLU, each K
+    block's tile read from a second VMEM operand.  No LRN or pool then.
+    Without it the kernel is traced as it always was.
     """
     p = plan(x.shape, w.shape, stride=stride, padding=padding, pool=pool,
              groups=groups, row_block=row_block,
              pool_row_block=pool_row_block, c_block=c_block,
-             k_block=k_block, batch_block=batch_block, checksum=checksum)
+             k_block=k_block, batch_block=batch_block, checksum=checksum,
+             residual=residual is not None)
     B, H, W, _ = x.shape
+    assert residual is None or (
+        pool is None and lrn is None
+        and residual.shape == (B, p.out_h, p.out_w, p.Kfull)), (
+        "a residual is added to the conv's own output, before any pool",
+        None if residual is None else residual.shape, pool, lrn)
     s, g = p.s, p.g
 
     # strided convs can leave trailing rows/cols no output window reads —
@@ -370,8 +421,8 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
 
     single = p.weights.n_tiles == 1
     row_par = bool(row_parallel) and not single
-    kernel = functools.partial(_direct_kernel, relu=relu,
-                               checksum=p.checksum, lrn=lrn,
+    kernel = functools.partial(_direct_kernel, residual=p.residual,
+                               relu=relu, checksum=p.checksum, lrn=lrn,
                                pool=pool, step_in=p.step_in,
                                prefetch=weight_prefetch,
                                single=single, row_parallel=row_par)
@@ -382,22 +433,32 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
     if p.checksum:
         out_specs.append(dma.verdict_spec())
         out_shape.append(dma.verdict_shape(p.Bp // p.Bb, p.npr))
+    in_specs = [
+        pl.BlockSpec((p.Bb, p.Hp, p.Wp, p.Cb),
+                     lambda bo, i, k, c, bi, nkb=p.nkb, ncb=p.ncb:
+                     (bo, 0, 0, (k // nkb) * ncb + c)),
+        # tile-packed weights: a single tile rides the BlockSpec pipeline
+        # (fetched once, resident); a multi-tile stream stays in ANY space
+        # and moves by manual double-buffered DMA
+        (dma.single_tile_spec(p.weights) if single
+         else pl.BlockSpec(memory_space=pl.ANY)),
+        # the whole (g*nkb, Kb) bias stays resident; the kernel picks row
+        # k (a (1, Kb) block would break the 8-row tiling rule)
+        pl.BlockSpec((g * p.nkb, p.Kb), lambda *_: (0, 0)),
+    ]
+    operands = [xg, w_tiles, bg]
+    if p.residual:
+        # padded to the scratch's (Rc, wc) tiles: each (row step, K block)
+        # reads its shortcut tile once, whole, beside the accumulator
+        operands.append(jnp.pad(residual.astype(x.dtype), (
+            (0, p.Bp - B), (0, p.npr * p.Rc - p.out_h),
+            (0, p.wc - p.out_w), (0, 0))))
+        in_specs.append(pl.BlockSpec((p.Bb, p.Rc, p.wc, p.Kb),
+                                     lambda bo, i, k, c, bi: (bo, i, 0, k)))
     res = pl.pallas_call(
         kernel,
         grid=(p.Bp // p.Bb, p.npr, g * p.nkb, p.ncb, p.Bb),
-        in_specs=[
-            pl.BlockSpec((p.Bb, p.Hp, p.Wp, p.Cb),
-                         lambda bo, i, k, c, bi, nkb=p.nkb, ncb=p.ncb:
-                         (bo, 0, 0, (k // nkb) * ncb + c)),
-            # tile-packed weights: a single tile rides the BlockSpec
-            # pipeline (fetched once, resident); a multi-tile stream stays
-            # in ANY space and moves by manual double-buffered DMA
-            (dma.single_tile_spec(p.weights) if single
-             else pl.BlockSpec(memory_space=pl.ANY)),
-            # the whole (g*nkb, Kb) bias stays resident; the kernel picks
-            # row k (a (1, Kb) block would break the 8-row tiling rule)
-            pl.BlockSpec((g * p.nkb, p.Kb), lambda *_: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -411,7 +472,7 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
             vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
         name=name,
-    )(xg, w_tiles, bg)
+    )(*operands)
 
     out = res[0]
     y = out[:B, :p.ph_out] if pool is not None else out[:B, :p.out_h]

@@ -7,7 +7,9 @@ scratch, and the very last (k, c) grid step runs the cross-channel LRN and
 VALID max-pool entirely in VMEM before writing only the pooled, normalized
 feature map to HBM.  This module is that shared tail — the epilogue math
 exists exactly once — plus the host-side channel/batch block helpers both
-``pallas_call`` setups use.
+``pallas_call`` setups use.  The direct kernel can also add a residual
+(a bottleneck's shortcut, a second VMEM operand tiled like its K block)
+between the bias and the ReLU, in the layers that have no LRN or pool.
 
 Everything here runs *inside* a kernel (on VMEM-resident arrays) except the
 ``*_blocks`` and ``vmem_limit`` helpers, which are host-side setup.
@@ -34,6 +36,9 @@ F32_DOT = jax.lax.Precision.HIGHEST
 # its 128 MiB, and Mosaic keeps in-kernel values on top of the buffers
 _VMEM_FLOOR = 32 * 2 ** 20
 _VMEM_CEILING = 110 * 2 ** 20
+# the most a launch's buffers may take and still leave the floor for
+# Mosaic's values under the ceiling
+VMEM_NEED_MAX = _VMEM_CEILING - _VMEM_FLOOR
 
 
 # ---------------------------------------------------------------------------
@@ -112,13 +117,19 @@ def channel_blocks(C: int, c_block: int | None, hp: int, wp: int,
     return min(c_block, C)
 
 
-def vmem_limit(pipelined, scratch, dtype_bytes: int = 4) -> int:
-    """Scoped-VMEM bytes to request for one kernel launch: the grid
-    pipeline double-buffers every ``pipelined`` block shape, ``scratch``
-    shapes are allocated once, all padded to the (8, 128) tile; the same
-    again (at least the floor) is left for Mosaic's in-kernel values."""
-    need = (2 * sum(vmem_bytes(s, dtype_bytes) for s in pipelined)
+def vmem_need(pipelined, scratch, dtype_bytes: int = 4) -> int:
+    """VMEM bytes one kernel launch's buffers take: the grid pipeline
+    double-buffers every ``pipelined`` block shape, ``scratch`` shapes are
+    allocated once, all padded to the (8, 128) tile."""
+    return (2 * sum(vmem_bytes(s, dtype_bytes) for s in pipelined)
             + sum(vmem_bytes(s, dtype_bytes) for s in scratch))
+
+
+def vmem_limit(pipelined, scratch, dtype_bytes: int = 4) -> int:
+    """Scoped-VMEM bytes to request for one kernel launch: its buffers
+    (:func:`vmem_need`) and the same again (at least the floor) for
+    Mosaic's in-kernel values."""
+    need = vmem_need(pipelined, scratch, dtype_bytes)
     return int(min(max(2 * need, need + _VMEM_FLOOR), _VMEM_CEILING))
 
 

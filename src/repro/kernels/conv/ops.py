@@ -116,13 +116,14 @@ def conv2d(x, w, b=None, w_packed=None, *, m: int = 4, padding: str = "SAME",
 
 
 def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
-                  padding: str = "SAME", relu: bool = False, groups: int = 1,
+                  padding="SAME", relu: bool = False, groups: int = 1,
                   lrn=None, pool=None, c_block: int | None = None,
                   pool_row_block: int | None = None, k_block: int = 128,
                   batch_block: int = 8,
                   weight_prefetch: bool = True, row_parallel: bool = False,
                   checksum: bool = False, pallas: bool = True,
-                  interpret: bool | None = None, name: str | None = None):
+                  interpret: bool | None = None, name: str | None = None,
+                  residual=None):
     """Fused direct conv layer for any kernel/stride geometry.
 
     ``pallas=True`` runs the strided stream-buffered kernel (``direct.py``)
@@ -130,10 +131,11 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
     ``pallas=False`` is the ``lax.conv_general_dilated`` oracle with the
     same fused-layer signature (``ref.conv2d_ref``).  ``checksum=True``
     returns ``(y, verdict)`` on both routes (constant 0 off-Pallas).
-    ``name`` names the Pallas kernel.
+    ``name`` names the Pallas kernel.  ``residual``, a shortcut of the
+    output's shape, is added before the ReLU (no LRN or pool then).
     """
     if pallas:
-        return _d.conv2d_direct(x, w, b, w_packed, stride=stride,
+        return _d.conv2d_direct(x, w, b, w_packed, residual, stride=stride,
                                 padding=padding, relu=relu, groups=groups,
                                 lrn=lrn, pool=pool, c_block=c_block,
                                 pool_row_block=pool_row_block,
@@ -143,6 +145,11 @@ def conv2d_direct(x, w, b=None, w_packed=None, *, stride: int = 1,
                                 row_parallel=row_parallel,
                                 checksum=checksum,
                                 interpret=_interp(interpret), name=name)
-    y = conv2d_ref(x, w, b, stride=stride, padding=padding, groups=groups,
-                   relu=relu, lrn=lrn, pool=pool)
+    if residual is not None:
+        y = conv2d_ref(x, w, b, stride=stride, padding=padding,
+                       groups=groups) + residual.astype(x.dtype)
+        y = jnp.maximum(y, 0) if relu else y
+    else:
+        y = conv2d_ref(x, w, b, stride=stride, padding=padding,
+                       groups=groups, relu=relu, lrn=lrn, pool=pool)
     return (y, jnp.zeros((), jnp.int32)) if checksum else y

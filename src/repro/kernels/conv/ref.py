@@ -16,19 +16,21 @@ def conv1d_depthwise_causal_ref(x, w, b=None):
     return y
 
 
-def conv2d_ref(x, w, b=None, *, stride: int = 1, padding: str = "SAME",
+def conv2d_ref(x, w, b=None, *, stride: int = 1, padding="SAME",
                groups: int = 1, relu: bool = False, lrn=None, pool=None):
     """lax direct conv with the fused-layer signature.
 
-    x (B,H,W,C), w (r,r,C//groups,K); optional bias (K,), fused ReLU,
-    grouped convolution via ``feature_group_count``, and the layer epilogue
-    — cross-channel LRN (``lrn``: LrnParams) then VALID max-pool (``pool``:
-    (window, stride)) — the oracle for every route of
+    x (B,H,W,C), w (r,r,C//groups,K); ``padding`` "SAME", "VALID" or
+    explicit ``(lo, hi)`` pads on each spatial side; optional bias (K,),
+    fused ReLU, grouped convolution via ``feature_group_count``, and the
+    layer epilogue — cross-channel LRN (``lrn``: LrnParams) then VALID
+    max-pool (``pool``: (window, stride)) — the oracle for every route of
     ``repro.nn.conv.dispatch_conv``.
     """
+    pads = padding if isinstance(padding, str) else (tuple(padding),) * 2
     y = jax.lax.conv_general_dilated(
         x.astype(jnp.float32), w.astype(jnp.float32),
-        window_strides=(stride, stride), padding=padding,
+        window_strides=(stride, stride), padding=pads,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=groups)
     if b is not None:

@@ -15,6 +15,7 @@ import dataclasses
 import numpy as np
 
 from ..configs import ASSIGNED, CNN_ARCHS, get_config
+from ..models import model_for
 from ..serving.compile_cache import enable_compile_cache
 from ..serving import (CnnEngine, CnnServeConfig, Engine, FaultInjector,
                        FaultSpec, ImageRequest, Request, ServeConfig,
@@ -107,13 +108,12 @@ def serve_images(cfg, args) -> int:
         # full SDC defense: ABFT checksums through the conv datapath,
         # pre-dispatch slab fingerprints, magnitude-bounded screen
         cfg = dataclasses.replace(cfg, sdc_abft=True)
-    if hasattr(cfg, "conv_channels"):
+    if hasattr(model_for(cfg), "layer_routes"):
         # per-layer resolved datapaths — `--route pallas` must show every
         # layer on a Pallas kernel, not a silent lax fallback — plus the
         # resolved §3.5 weight-stream mode (double-buffered DMA vs
         # synchronous fetches; lax/jnp routes have no in-kernel stream)
-        from ..models.alexnet import layer_routes
-        routes = layer_routes(cfg)
+        routes = model_for(cfg).layer_routes(cfg)
         pallas_any = any(r.startswith("pallas") for _, r in routes)
         mode = (("on(dma-double-buffer)" if cfg.weight_prefetch
                  else "off(dma-sync)") if pallas_any else "n/a(no-dma-route)")

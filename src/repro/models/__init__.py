@@ -4,6 +4,9 @@ from . import lm  # noqa: F401
 def model_for(cfg):
     """Dispatch to the model family implementation."""
     if cfg.family == "cnn":
+        if cfg.arch == "resnet":
+            from . import resnet
+            return resnet
         from . import alexnet
         return alexnet
     if cfg.family == "audio":

@@ -150,11 +150,18 @@ MODEL_ROUTES = {
 }
 
 
-def conv_out_hw(extent: int, kernel: int, stride: int, padding: str) -> int:
-    """Conv output extent (lax SAME/VALID semantics) — the one formula
-    every spec/guard/model shares."""
-    return ((extent - kernel) // stride + 1 if padding == "VALID"
-            else -(-extent // stride))
+def conv_out_hw(extent: int, kernel: int, stride: int, padding) -> int:
+    """Conv output extent (lax SAME/VALID semantics, or explicit
+    ``(lo, hi)`` pads on each spatial side) — the one formula every
+    spec/guard/model shares."""
+    return _direct_k.pads(extent, kernel, stride, padding)[0]
+
+
+def conv_pads(extent: int, kernel: int, stride: int, padding) -> tuple:
+    """``(lo, hi)`` zero pads the conv reads on each side of a spatial axis
+    of ``extent``: lax's split for SAME (the extra pad goes last), none for
+    VALID, explicit pads as given."""
+    return _direct_k.pads(extent, kernel, stride, padding)[1:]
 
 
 @dataclass(frozen=True)
@@ -167,7 +174,7 @@ class ConvSpec:
     """
     kernel: int
     stride: int = 1
-    padding: str = "SAME"           # "SAME" | "VALID"
+    padding: str | tuple = "SAME"   # "SAME" | "VALID" | (lo, hi) pads
     groups: int = 1
     fuse_bias: bool = True          # apply bias inside the conv call
     relu: bool = False              # fused ReLU epilogue
@@ -181,7 +188,10 @@ class ConvSpec:
 
     def __post_init__(self):
         assert self.route in ROUTES, self.route
-        assert self.padding in ("SAME", "VALID"), self.padding
+        assert (self.padding in ("SAME", "VALID")
+                or (isinstance(self.padding, tuple)
+                    and len(self.padding) == 2
+                    and min(self.padding) >= 0)), self.padding
         assert self.pool_window >= 1 and self.pool_stride >= 1
 
     def with_route(self, route: str) -> "ConvSpec":
@@ -189,7 +199,10 @@ class ConvSpec:
 
     @property
     def winograd_eligible(self) -> bool:
-        return self.stride == 1 and self.kernel == 3
+        """3x3 stride 1 with lax SAME/VALID padding: the Winograd kernels
+        take no explicit pads (a stride-1 3x3 SAME conv pads (1, 1))."""
+        return (self.stride == 1 and self.kernel == 3
+                and isinstance(self.padding, str))
 
     def out_hw(self, h: int) -> int:
         """Layer output extent for input extent ``h`` (conv then pool)."""
@@ -460,7 +473,7 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
                   k_block=UNSET, batch_block=UNSET, c_block=UNSET,
                   pool_row_block=UNSET, row_parallel=UNSET,
                   abft: bool = False, prefetch_next=None,
-                  name: str | None = None):
+                  name: str | None = None, residual=None):
     """Run one conv layer per its spec.  x (B,H,W,C), w (k,k,C//g,K), b (K,).
 
     Grouped convs are batched (``feature_group_count`` on the direct route,
@@ -498,8 +511,16 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
     ``name`` is the layer's key (``conv3``): a Pallas kernel it launches is
     named ``<name>_<kernel>`` (``conv3_winograd``, ``conv1_direct``), the
     name its device op carries in a profile.
+
+    ``residual`` (B, Ho, Wo, K), the shortcut of a residual block, is
+    added after the bias and before the ReLU: conv -> +b -> +residual ->
+    relu.  The direct kernel adds it in its epilogue, as a second VMEM
+    operand, and is then named ``<name>_direct_add``; every other route
+    adds it after the conv.  A layer with a residual has no LRN or pool.
     """
     assert w.shape[0] == w.shape[1] == spec.kernel, (w.shape, spec.kernel)
+    assert residual is None or not (spec.fuse_lrn or spec.fuse_pool), (
+        "a residual add ends its layer: no LRN or pool after it")
     knobs = plan_knobs(plan, batch_block=batch_block, k_block=k_block,
                        c_block=c_block, pool_row_block=pool_row_block,
                        weight_prefetch=weight_prefetch,
@@ -511,12 +532,18 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
     # deferred along with it.
     defer_bias = b is not None and not spec.fuse_bias
     bias = b if spec.fuse_bias else None
-    relu = spec.relu and not defer_bias
+    kernel = resolve_kernel(spec, in_hw=(x.shape[1], x.shape[2]))
+    # the direct kernel adds a residual in its epilogue; elsewhere it is
+    # added after the conv, so the ReLU moves after it too
+    fuse_res = (residual is not None and kernel == "pallas-direct"
+                and not defer_bias)
+    late_res = residual is not None and not fuse_res
+    relu = spec.relu and not defer_bias and not late_res
     lrn_p = spec.lrn if spec.fuse_lrn and not defer_bias else None
     pool = ((spec.pool_window, spec.pool_stride)
             if spec.fuse_pool and not defer_bias else None)
-    kernel = resolve_kernel(spec, in_hw=(x.shape[1], x.shape[2]))
     kname = (f"{name}_{kernel.removeprefix('pallas-')}"
+             + ("_add" if fuse_res else "")
              if name and kernel.startswith("pallas") else None)
 
     slab = None
@@ -562,7 +589,8 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
                                  weight_prefetch=knobs.weight_prefetch,
                                  row_parallel=knobs.row_parallel,
                                  checksum=abft, pallas=True,
-                                 interpret=interpret, name=kname)
+                                 interpret=interpret, name=kname,
+                                 residual=residual if fuse_res else None)
     else:  # winograd (pure-jnp, differentiable)
         y = conv2d_winograd(x, w, bias, m=spec.winograd_m,
                             padding=spec.padding, relu=relu,
@@ -577,8 +605,11 @@ def dispatch_conv(spec: ConvSpec, x, w, b=None, *, interpret=None,
         prefetch_next()             # stage layer N+1 behind this dispatch
     if defer_bias:
         y = y + b.astype(y.dtype)
-        if spec.relu:
-            y = jnp.maximum(y, 0)
+    if late_res:
+        y = y + residual.astype(y.dtype)
+    if (defer_bias or late_res) and spec.relu:
+        y = jnp.maximum(y, 0)
+    if defer_bias:
         y = apply_epilogue(y,
                            spec.lrn if spec.fuse_lrn else None,
                            (spec.pool_window, spec.pool_stride)

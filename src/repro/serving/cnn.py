@@ -26,11 +26,13 @@ reproduces that request-to-prediction path in software on top of the shared
   ``PackedConvWeights`` pytree), so the serving graph consumes staged
   slabs instead of re-packing filters in-trace every call.
 * **Double-buffered staging** — host->device image copies are dispatched
-  asynchronously up to ``staging_depth`` groups ahead, so the H2D transfer
-  of group N+1 overlaps the forward pass of group N — the software analogue
-  of the §3.5 stream buffers (``core/streambuf.py`` is the training-input
-  twin of the same idea).  The slot pool is sized ``max_batch *
-  staging_depth`` so a full bucket can stage while another computes.
+  asynchronously up to ``staging_depth`` groups ahead; :meth:`step`
+  launches group N before it stages group N+1, so the host copy and the
+  H2D transfer of group N+1 overlap the forward pass of group N — the
+  software analogue of the §3.5 stream buffers (``core/streambuf.py`` is
+  the training-input twin of the same idea).  The slot pool is sized
+  ``max_batch * staging_depth`` so a full bucket can stage while another
+  computes.
 * **Data parallelism** — with ``data_parallel=True`` the parameters are
   replicated over a 1-axis ``"data"`` mesh and each bucket's batch axis is
   sharded across devices (``parallel/sharding.py``).  The forward runs under
@@ -952,9 +954,10 @@ class CnnEngine:
         self.bucket_counts[g.bucket] = self.bucket_counts.get(g.bucket, 0) + 1
 
     def step(self):
-        """One tick: pump elapsed retries, stage ahead (H2D), launch the
-        oldest staged, retire the oldest computed — transfer, compute, and
-        host retirement overlap.  Under quarantine the circuit is open:
+        """One tick: pump elapsed retries, launch the oldest staged group,
+        stage the next (its host copy and H2D transfer overlap that
+        forward; a group staged on an idle device launches at once),
+        retire the oldest computed.  Under quarantine the circuit is open:
         nothing launches except the half-open probe after ``cooldown_ms``,
         and queued work drains via deadline expiry.  No Python exception
         escapes this method for launch/device failures — they feed the
@@ -975,8 +978,10 @@ class CnnEngine:
                     else:
                         self.health.cancel_probe()  # nothing admissible
             else:
-                self._stage()
                 self._launch()
+                self._stage()
+                if not self._compute:
+                    self._launch()
             self._finish_oldest()
         self._t_serve += self.clock.now() - t0
 

@@ -140,6 +140,34 @@ def test_incremental_submission_reuses_buckets(served):
         np.testing.assert_allclose(r.logits, expect[i], rtol=1e-5, atol=1e-6)
 
 
+def test_next_group_stages_while_the_last_computes(served):
+    """Every step after the first launches the group staged the step
+    before, then stages the next: the host copy and its H2D transfer run
+    while a forward is in flight, and logits are unchanged."""
+    cfg, params, ref = served
+    eng = CnnEngine(cfg, CnnServeConfig(max_batch=2), params=params)
+    imgs = _images(cfg, 8, seed=12)
+    reqs = [ImageRequest(image=im) for im in imgs]
+    for r in reqs:
+        eng.submit(r)
+    in_flight, stage = [], eng._stage
+
+    def spy():
+        in_flight.append(len(eng._compute))
+        stage()
+
+    eng._stage = spy
+    eng.step()
+    assert in_flight == [0] and len(eng._compute) == 0
+    assert len(eng._staged) == 1    # the second group waits for next step
+    eng.run_until_done()
+    assert in_flight[1:4] == [1, 1, 1]
+    assert eng.bucket_counts == {2: 4}
+    expect = np.asarray(ref(jnp.asarray(imgs)))
+    for i, r in enumerate(reqs):
+        np.testing.assert_allclose(r.logits, expect[i], rtol=1e-5, atol=1e-6)
+
+
 def test_submit_rejects_wrong_image_shape(served):
     """Shape errors surface at the API boundary, not via silent numpy
     broadcasting deep inside staging."""
